@@ -804,43 +804,36 @@ def _multidevice_section(*, n_requests: int, slots: int, seed: int) -> Dict:
 
 def run_multidevice(*, n_requests: int, slots: int, seed: int) -> Dict:
     """The ``multidevice`` section: async hand-off overlap, two-device
-    throughput and mid-run migration under a forced two-device host.
+    throughput and mid-run migration.
 
-    ``--xla_force_host_platform_device_count`` only works before the
-    first jax import, and this process already initialized its backend —
-    so the legs run in a subprocess carrying the flag (the same world the
-    CI multidevice job and ``tests/test_multidevice.py`` exercise).  If
-    the subprocess fails the section is measured in-process on whatever
-    devices exist; ``n_devices``/``distinct_devices`` record which, and
-    ``check_regression`` gates the overlap/throughput claims only on a
-    genuinely distinct assignment."""
-    import subprocess
-    import sys
-
-    from repro.launch.mesh import forced_host_device_env
-
-    cmd = [sys.executable, "-m", "benchmarks.bench_serving",
-           "--multidevice-child", "--requests", str(n_requests),
-           "--slots", str(slots)]
-    try:
-        proc = subprocess.run(cmd, env=forced_host_device_env(2),
-                              capture_output=True, text=True, timeout=1800)
-    except (OSError, subprocess.TimeoutExpired) as e:
-        proc = None
-        print(f"[bench_serving] multidevice subprocess failed: {e!r}",
-              flush=True)
-    if proc is not None and proc.returncode == 0:
-        section = json.loads(proc.stdout.strip().splitlines()[-1])
-        section["forced_subprocess"] = True
-    else:
-        if proc is not None:
-            print(f"[bench_serving] multidevice subprocess exited "
-                  f"{proc.returncode}: {proc.stderr[-2000:]}", flush=True)
-        print("[bench_serving] multidevice: degrading to in-process "
-              "devices", flush=True)
+    On the CPU backend the legs run in a child process that splits the
+    host into two devices: ``--xla_force_host_platform_device_count``
+    only works before the first jax import, and this process already
+    initialized its backend (the same world the CI multidevice job and
+    ``tests/test_multidevice.py`` exercise).  A failed child fails the
+    bench.  On an accelerator the legs run in this process, on its real
+    devices — a child would contend with this process for the chips."""
+    if jax.default_backend() != "cpu":
         section = _multidevice_section(n_requests=n_requests, slots=slots,
                                        seed=seed)
         section["forced_subprocess"] = False
+    else:
+        import subprocess
+        import sys
+
+        from repro.launch.mesh import forced_host_device_env
+
+        cmd = [sys.executable, "-m", "benchmarks.bench_serving",
+               "--multidevice-child", "--requests", str(n_requests),
+               "--slots", str(slots)]
+        proc = subprocess.run(cmd, env=forced_host_device_env(2),
+                              capture_output=True, text=True, timeout=1800)
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"multidevice child exited {proc.returncode}: "
+                f"{proc.stderr[-2000:]}")
+        section = json.loads(proc.stdout.strip().splitlines()[-1])
+        section["forced_subprocess"] = True
     mig = section["migration"]
     print(f"[bench_serving] multidevice[{section['assignment']['prefill']}"
           f"|{section['assignment']['decode']}]: async "

@@ -179,6 +179,18 @@ def get(name: str) -> DeviceModel:
     return REGISTRY[name]
 
 
+# the attached device (``jax.Device.device_kind``) -> the model that prices
+# work on it.  A kind missing here is an error, never a default.
+BY_DEVICE_KIND = {"TPU v5 lite": TPU_V5E}
+
+
+def for_device_kind(kind: str) -> DeviceModel:
+    if kind not in BY_DEVICE_KIND:
+        raise KeyError(f"no device model for device kind {kind!r} (known: "
+                       f"{', '.join(sorted(BY_DEVICE_KIND))})")
+    return BY_DEVICE_KIND[kind]
+
+
 def register(model: DeviceModel, *, overwrite: bool = False) -> DeviceModel:
     """Add a model (e.g. a profiling-calibrated one) to the registry so
     name-keyed consumers — the serving batcher's ``device_name`` — can

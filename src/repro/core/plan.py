@@ -76,7 +76,8 @@ def compile_plan(
     before building (no-op if the plan was already measured-priced), so the
     compiled program follows measurements rather than the analytic
     hypothesis.  The plan actually built — re-priced or not — is attached
-    to the returned callable as ``.plan``.
+    to the returned callable as ``.plan``, and the engine that runs each
+    layer (after the fallback) as ``.engines``.
     """
     if price is not None and price != plan.pricing:
         plan = reprice_plan(plan, engines=engines, price=price,
@@ -86,12 +87,13 @@ def compile_plan(
     if engines:
         by_name.update({e.name: e for e in engines})
 
-    fns = []
+    fns, ran_on = [], []
     for a in plan.assignments:
         eng = by_name[a.engine]
         if not eng.buildable:
             eng = by_name[fallback]
         fns.append(eng.build(a.spec))
+        ran_on.append(eng.name)
 
     def apply(x: jax.Array, params: List[Dict[str, jax.Array]]) -> jax.Array:
         for fn, p in zip(fns, params):
@@ -99,4 +101,5 @@ def compile_plan(
         return x
 
     apply.plan = plan
+    apply.engines = tuple(ran_on)
     return apply

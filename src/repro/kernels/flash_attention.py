@@ -26,15 +26,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import tpu_compiler_params
-
 _NEG_INF = -1e30
 _LANES = 128
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                   nk: int, bq: int, bk: int, scale: float, causal: bool,
-                  window: Optional[int]):
+                  window: Optional[int], kv_len: Optional[int]):
     iq, ik = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -49,6 +47,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         run &= ik * bk <= iq * bq + bq - 1
     if window is not None:
         run &= (ik + 1) * bk - 1 >= iq * bq - (window - 1)
+    if kv_len is not None:
+        run &= ik * bk < kv_len
 
     @pl.when(run)
     def _body():
@@ -64,6 +64,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             mask &= kpos <= qpos
         if window is not None:
             mask &= kpos > qpos - window
+        if kv_len is not None:
+            mask &= kpos < kv_len
         s = jnp.where(mask, s, _NEG_INF)
 
         m_prev = m_scr[:, :1]                           # (bq, 1)
@@ -95,10 +97,12 @@ def flash_attention_pallas(
     scale: Optional[float] = None,
     block_q: int = 512,
     block_k: int = 512,
+    kv_len: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
     """q: (B, HQ, S, D); k/v: (B, HK, T, D) with HQ % HK == 0.  S % bq == 0,
-    T % bk == 0 (ops.py pads otherwise).  Returns (B, HQ, S, D)."""
+    T % bk == 0 (ops.py pads otherwise).  ``kv_len`` < T masks the padded
+    key rows past it.  Returns (B, HQ, S, D)."""
     b, hq, s, d = q.shape
     _, hk, t, _ = k.shape
     assert hq % hk == 0, (hq, hk)
@@ -117,7 +121,8 @@ def flash_attention_pallas(
 
     kernel = functools.partial(
         _flash_kernel, nk=t // bk, bq=bq, bk=bk, scale=scale, causal=causal,
-        window=window)
+        window=window, kv_len=kv_len if kv_len is not None and kv_len < t
+        else None)
     out = pl.pallas_call(
         kernel,
         grid=(b * hq, s // bq, t // bk),
@@ -133,7 +138,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

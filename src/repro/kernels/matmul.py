@@ -16,8 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import tpu_compiler_params
-
 
 def _matmul_kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int, activation: str,
                    bias_ref=None):
@@ -70,8 +68,8 @@ def matmul_pallas(
     ]
     args = [x, w]
     if bias is not None:
-        in_specs.append(pl.BlockSpec((bn,), lambda i, j, kk: (j,)))
-        args.append(bias)
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)))
+        args.append(bias.reshape(1, n))
         kernel = functools.partial(
             _matmul_with_bias_kernel, nk=nk, activation=activation)
     else:
@@ -84,7 +82,7 @@ def matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

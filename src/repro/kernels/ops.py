@@ -2,8 +2,9 @@
 
 Responsibilities:
 * pad arbitrary shapes to kernel block alignment and unpad results;
-* pick interpret mode automatically (this container is CPU-only; on a real
-  TPU `interpret=False` compiles to Mosaic);
+* pick the execution mode from the backend: Mosaic (`interpret=False`) on a
+  TPU, the Pallas interpreter on the CPU (tests), and an error anywhere
+  else — a kernel never silently runs as something other than itself;
 * expose a uniform signature the execution-engine registry
   (core/engines.py) can build against.
 """
@@ -15,8 +16,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from . import ref
-from .compat import has_scalar_prefetch
 from .conv2d import conv2d_pallas
 from .flash_attention import flash_attention_pallas
 from .lrn import lrn_pallas
@@ -26,7 +25,15 @@ from .pooling import pool_pallas
 
 
 def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret mode on the CPU, compiled kernels on a TPU; any other
+    backend has no Pallas TPU lowering and no business interpreting."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas TPU kernels cannot run on backend "
+                       f"{backend!r}: use a TPU, or the CPU in interpret mode")
 
 
 def _pad_to(x: jax.Array, axis: int, multiple: int) -> jax.Array:
@@ -100,33 +107,24 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     bq, bk = min(block_q, s), min(block_k, t)
     sp, tp = s + (-s) % bq, t + (-t) % bk
     if sp != s or tp != t:
-        # pad queries at the END, keys at the END; causal mask keeps padded
-        # keys (positions >= t... but padded *queries* would attend) — since
-        # we slice padded query rows off, only padded KEYS matter: they sit at
-        # positions > every real query, so the causal mask removes them.  For
-        # non-causal (encoder) calls we must mask explicitly — ref handles it.
-        if not causal:
-            return ref.attention_ref(q, k, v, causal=causal, window=window)
+        # pad queries and keys at the END; padded query rows are sliced off,
+        # and the kernel masks the padded keys past the real length t
         q = jnp.pad(q, ((0, 0), (0, 0), (0, sp - s), (0, 0)))
         k = jnp.pad(k, ((0, 0), (0, 0), (0, tp - t), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, tp - t), (0, 0)))
     out = flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                 block_q=bq, block_k=bk, interpret=interpret)
+                                 block_q=bq, block_k=bk, kv_len=t,
+                                 interpret=interpret)
     return out[:, :, :s, :]
 
 
-@functools.partial(jax.jit, static_argnames=("max_seq", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
                     block_tables: jax.Array, pos: jax.Array, *,
-                    max_seq: Optional[int] = None,
                     interpret: Optional[bool] = None) -> jax.Array:
     """Paged decode attention: q (B, HQ, 1, D) against block arenas
-    (TB, HK, BS, D) gathered through (B, NB) block tables.  Degrades to the
-    pure-jnp gather oracle on jaxlibs without scalar prefetch."""
+    (TB, HK, BS, D) gathered through (B, NB) block tables."""
     interpret = default_interpret() if interpret is None else interpret
-    if not has_scalar_prefetch():
-        return ref.paged_attention_ref(q, k_arena, v_arena, block_tables,
-                                       pos, max_seq=max_seq)
     return paged_attention_pallas(q, k_arena, v_arena, block_tables, pos,
                                   interpret=interpret)
 

@@ -25,8 +25,6 @@ TPU-native choices:
 The pure-jnp oracle is kernels/ref.py:paged_attention_ref (gather through
 the table, then dense decode attention); it is also what the serving
 engine runs on CPU, where bit-identity with the dense KV path is asserted.
-Scalar prefetch predates some supported jaxlibs — kernels/compat.py gates
-it, and ops.paged_attention falls back to the oracle when absent.
 """
 from __future__ import annotations
 
@@ -36,8 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from .compat import prefetch_grid_spec, tpu_compiler_params
 
 _NEG_INF = -1e30
 _LANES = 128
@@ -124,7 +120,7 @@ def paged_attention_pallas(
         del pos_ref
         return (bt_ref[bh // hk, j], bh % hk, 0, 0)
 
-    grid_spec = prefetch_grid_spec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                         # block_tables, pos
         grid=(b * hk, nb),
         in_specs=[
@@ -139,18 +135,13 @@ def paged_attention_pallas(
             pltpu.VMEM((group, d), jnp.float32),
         ],
     )
-    if grid_spec is None:
-        raise NotImplementedError(
-            "this jaxlib has no PrefetchScalarGridSpec; use "
-            "ref.paged_attention_ref (ops.paged_attention degrades "
-            "automatically)")
     kernel = functools.partial(_paged_kernel, hk=hk, nb=nb, bs=bs,
                                scale=scale)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, group, d), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

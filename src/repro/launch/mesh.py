@@ -47,10 +47,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
             f"visible. For the dry-run, set XLA_FLAGS="
             f"--xla_force_host_platform_device_count=512 BEFORE importing "
             f"jax (launch/dryrun.py does this).")
-    try:
-        return jax.make_mesh(shape, axes, devices=devs[:need])
-    except TypeError:  # older make_mesh without `devices`
-        return Mesh(np.asarray(devs[:need]).reshape(shape), axes)
+    return jax.make_mesh(shape, axes, devices=devs[:need])
 
 
 def make_host_mesh() -> Mesh:

@@ -23,6 +23,7 @@ from ..core.layer_model import (ConvSpec, FCSpec, NetworkSpec, alexnet_spec,
                                 alexnet_full_spec)
 from ..profiling import (MeasuredPricer, ProfileCache, calibration_report,
                          profile_network)
+from .compile_cache import enable_compilation_cache
 
 _DTYPES = {"float32": jnp.float32, "bfloat16": jnp.bfloat16}
 
@@ -70,6 +71,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main() -> None:
     args = build_parser().parse_args()
+    enable_compilation_cache()
 
     net = NETS[args.net]()
     if args.engines:

@@ -62,6 +62,7 @@ from __future__ import annotations
 import argparse
 
 from ..serving import placement as placement_lib
+from .compile_cache import enable_compilation_cache
 # re-exported for compatibility: the static server and param builder grew
 # up here before the programmatic API extracted them
 from ..serving.api import (ServeOptions, ServeReport, Server,  # noqa: F401
@@ -279,6 +280,7 @@ def main() -> None:
         options.validate()
     except ValueError as err:
         ap.error(str(err))
+    enable_compilation_cache()
     try:
         serve(options, verbose=True)
     except ValueError as err:

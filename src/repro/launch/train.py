@@ -35,6 +35,7 @@ from ..models import sharding as shard_lib
 from ..models import transformer as T
 from ..optim import adamw, compression, schedules
 from ..runtime import PreemptionHandler, StepTimer
+from .compile_cache import enable_compilation_cache
 from .mesh import make_host_mesh, make_production_mesh
 
 
@@ -63,6 +64,7 @@ def main() -> None:
                                                        "multipod"])
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compilation_cache()
 
     arch = registry.get(args.arch)
     cfg = arch.smoke if args.scale == "smoke" else arch.config

@@ -263,8 +263,7 @@ def paged_decode_attention(q, k_arena, v_arena, block_tables, pos, *,
     inside the kernel (online softmax — allclose, not bit-identical).
     """
     if impl == "pallas":
-        out = kops.paged_attention(q, k_arena, v_arena, block_tables, pos,
-                                   max_seq=max_seq)
+        out = kops.paged_attention(q, k_arena, v_arena, block_tables, pos)
         return out.astype(q.dtype)
     if impl != "ref":
         raise ValueError(f"unknown paged attention impl {impl!r}")

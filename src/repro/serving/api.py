@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs import registry
-from ..launch.mesh import (device_assignment, make_host_mesh,
+from ..launch.mesh import (device_assignment, device_label, make_host_mesh,
                            make_production_mesh)
 from ..models import sharding as shard_lib
 from ..models import transformer as T
@@ -301,6 +301,7 @@ class ServeReport:
     pool_stats: Dict[str, Dict] = dataclasses.field(default_factory=dict)
     admission: List[Dict] = dataclasses.field(default_factory=list)
     handoff: Optional[Dict] = None
+    devices: Optional[Dict[str, str]] = None     # phase -> device label
     watchdog: Optional[Dict] = None
     slo: Optional[List] = None
     placement: Optional[Dict] = None
@@ -761,6 +762,9 @@ def serve(options: ServeOptions, *, verbose: bool = False,
                  ("decode", engine.decode.pool))
         batchers = engine.batchers
         handoff_stats = engine.handoff.stats()
+        devices = {ph: device_label(eng.device) for ph, eng in
+                   (("prefill", engine.prefill), ("decode", engine.decode))
+                   if eng.device is not None} or None
         decode_target = engine.decode_target
         for key, v in handoff_stats.items():
             val = f"{v:.4f}" if isinstance(v, float) else str(v)
@@ -796,6 +800,7 @@ def serve(options: ServeOptions, *, verbose: bool = False,
         pools = (("", engine.pool),)
         batchers = (engine.batcher,)
         handoff_stats = None
+        devices = None
         decode_target = None
     summary = metrics.summary()
     for key, v in summary.items():
@@ -922,6 +927,7 @@ def serve(options: ServeOptions, *, verbose: bool = False,
     return ServeReport(
         summary=summary, metrics=metrics, requests=list(requests),
         pool_stats=pool_stats, admission=admission,
-        handoff=handoff_stats, watchdog=watchdog_report, slo=slo_rows,
+        handoff=handoff_stats, devices=devices, watchdog=watchdog_report,
+        slo=slo_rows,
         placement=placement_report, decode_target=decode_target,
         speculation=spec_report)

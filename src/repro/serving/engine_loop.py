@@ -328,8 +328,13 @@ class SlotEngine:
 
     def dispatch(self, burst: int, active_np: np.ndarray) -> None:
         """Dispatch `burst` fused steps over the active slots (bucketed
-        power-of-two scans, no host sync)."""
-        active_dev = jnp.asarray(active_np)
+        power-of-two scans, no host sync).
+
+        The mask is copied first: on the CPU a suitably aligned numpy
+        array becomes a device array without a copy, so a caller flipping
+        ``active_np`` (bind, release) before the queued bursts execute
+        would change which slots they step."""
+        active_dev = jnp.asarray(active_np.copy())
         k = burst
         while k > 0:
             b = min(self.MAX_BUCKET, 1 << (k.bit_length() - 1))

@@ -189,3 +189,47 @@ def test_cache_shardings_kv_and_ssm():
     spec = getattr(kv[0], "spec", kv[0])   # FakeMesh returns bare P
     # mixtral kv=8 heads won't shard over 16 -> time dim takes 'model'
     assert spec[3] == "model" and spec[1] == "data"
+
+
+# ------------------------------------------------------ compilation cache
+_CACHE_PROBE = """
+import jax, jax.numpy as jnp
+from repro.launch.compile_cache import enable_compilation_cache
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+print(enable_compilation_cache())
+jax.jit(lambda x: jnp.sin(x) @ x.T)(jnp.ones((32, 32))).block_until_ready()
+"""
+
+
+def test_compilation_cache_lands_in_env_dir(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, entries land there and only
+    there; the helper sets no directory of its own."""
+    from repro.launch.compile_cache import CHECKOUT_CACHE_DIR
+    before = (set(os.listdir(CHECKOUT_CACHE_DIR))
+              if CHECKOUT_CACHE_DIR.exists() else set())
+    env = dict(os.environ, PYTHONPATH="src", JAX_ENABLE_COMPILATION_CACHE="1",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path))
+    r = subprocess.run([sys.executable, "-c", _CACHE_PROBE],
+                       capture_output=True, text=True, env=env, timeout=300,
+                       cwd=CHECKOUT_CACHE_DIR.parent)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.strip() == str(tmp_path)
+    assert os.listdir(tmp_path)
+    after = (set(os.listdir(CHECKOUT_CACHE_DIR))
+             if CHECKOUT_CACHE_DIR.exists() else set())
+    assert after == before
+
+
+def test_compilation_cache_defaults_to_checkout(monkeypatch):
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        path = compile_cache.enable_compilation_cache()
+        assert path == str(compile_cache.CHECKOUT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compile_cache.CHECKOUT_CACHE_DIR.name == ".jax_cache"
+        assert (compile_cache.CHECKOUT_CACHE_DIR.parent / "pyproject.toml"
+                ).exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

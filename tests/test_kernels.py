@@ -1,6 +1,8 @@
 """Per-kernel correctness: sweep shapes x dtypes, assert_allclose vs the
-pure-jnp oracles in kernels/ref.py.  All Pallas kernels run interpret=True
-(CPU container; TPU is the lowering target)."""
+pure-jnp oracles in kernels/ref.py.  On the CPU backend the Pallas kernels
+run in interpret mode; tests/test_tpu_compile.py compiles them for the
+chip."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -58,6 +60,8 @@ def test_matmul_block_sweep(rng):
     (24, 3, 16, 5, 2, 2),
     (13, 8, 16, 3, 1, 1),      # conv3-5 geometry (reduced channels)
     (12, 3, 8, 11, 4, 2),      # conv1 geometry (reduced)
+    (31, 3, 16, 11, 4, 2),     # stride-4 k11: space-to-depth, 7x7 out
+    (27, 8, 16, 5, 1, 2),      # conv2 geometry: 27x27 k5, odd width
 ])
 def test_conv2d_shapes(rng, hw, cin, cout, kk, stride, pad, dtype):
     x = jnp.asarray(rng.normal(size=(2, hw, hw, cin)), dtype)
@@ -73,6 +77,7 @@ def test_conv2d_shapes(rng, hw, cin, cout, kk, stride, pad, dtype):
 @pytest.mark.parametrize("pool_type", ["max", "avg"])
 @pytest.mark.parametrize("hw,c,win,stride", [
     (13, 8, 3, 2), (27, 4, 3, 2), (8, 16, 2, 2), (9, 3, 3, 3),
+    (55, 8, 3, 2), (11, 5, 3, 2),    # 3x3/s2 on odd sizes (Pool1 geometry)
 ])
 def test_pool_shapes(rng, hw, c, win, stride, pool_type):
     x = jnp.asarray(rng.normal(size=(2, hw, hw, c)), jnp.float32)
@@ -83,9 +88,15 @@ def test_pool_shapes(rng, hw, c, win, stride, pool_type):
 
 
 # ------------------------------------------------------------------ lrn
-@pytest.mark.parametrize("c,local", [(8, 5), (16, 3), (96, 5), (7, 5)])
-def test_lrn_shapes(rng, c, local):
-    x = jnp.asarray(rng.normal(size=(2, 7, 7, c)), jnp.float32)
+# scale 100: activations large enough that the windowed sum moves the
+# denominator (alpha/n * sum ~ k), so a wrong channel window cannot hide;
+# C=130 is not a multiple of the 128-lane tile
+@pytest.mark.parametrize("c,local,scale", [
+    pytest.param(c, n, s, id=f"{c}-{n}" + ("" if s == 1 else f"-x{s:g}"))
+    for c, n, s in [(8, 5, 1), (16, 3, 1), (96, 5, 1), (7, 5, 1),
+                    (96, 5, 100), (130, 5, 100), (7, 3, 100)]])
+def test_lrn_shapes(rng, c, local, scale):
+    x = jnp.asarray(scale * rng.normal(size=(2, 7, 7, c)), jnp.float32)
     got = ops.lrn(x, local_size=local)
     want = ref.lrn_ref(x, local_size=local)
     _assert_close(got, want, jnp.float32)
@@ -122,6 +133,28 @@ def test_flash_attention_unaligned_padding():
     got = ops.flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
     want = ref.attention_ref(q, k, v, causal=True)
     _assert_close(got, want, jnp.bfloat16)
+
+
+@pytest.mark.parametrize("s,t", [(100, 100), (64, 100)])
+def test_flash_attention_noncausal_padded_keys(s, t):
+    # encoder-style calls: the kernel masks the padded keys itself
+    rng = np.random.default_rng(2)
+    q = jnp.asarray(rng.normal(size=(1, 2, s, 32)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(1, 2, t, 32)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(1, 2, t, 32)), jnp.float32)
+    got = ops.flash_attention(q, k, v, causal=False, block_q=64,
+                              block_k=64)
+    want = ref.attention_ref(q, k, v, causal=False)
+    _assert_close(got, want, jnp.bfloat16)
+
+
+def test_interpret_mode_only_on_cpu(monkeypatch):
+    assert ops.default_interpret() is (jax.default_backend() == "cpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.default_interpret() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.default_interpret()
 
 
 # ------------------------------------------------------ paged attention

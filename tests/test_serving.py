@@ -6,7 +6,7 @@ import pytest
 
 from repro.models import transformer as T
 from repro.serving import (ContinuousBatcher, EngineLoop, KVPool, Request,
-                           RequestState, decode_network_spec,
+                           RequestState, SlotEngine, decode_network_spec,
                            step_time_model, synthetic_workload,
                            token_budget_for_slo)
 
@@ -204,6 +204,38 @@ def test_engine_recycles_slots_and_accounts_pool(tiny_params):
     s = metrics.summary()
     assert s["tokens_out"] == 24
     assert s["ttft_p50_s"] > 0 and s["latency_p99_s"] > 0
+
+
+def test_dispatch_snapshots_the_active_mask(tiny_params):
+    """A burst steps the slots that were active when it was dispatched,
+    even when the caller changes its mask before the device runs the
+    burst: on the CPU an aligned numpy array can become a device array
+    without a copy."""
+    pool = KVPool(n_slots=2, max_seq=8)
+    eng = SlotEngine(TINY, tiny_params, pool)
+    req = Request(rid=0, prompt=np.asarray([3], np.int32), max_new_tokens=2)
+    req.slot = pool.alloc(0, 3)
+    eng.bind(req, steps_total=2)
+    buf = np.zeros(128, np.uint8)
+    off = (-buf.ctypes.data) % 64
+    mask = buf[off:off + 2].view(bool)
+    mask[req.slot] = True
+    seen = []
+    burst_fn = eng._burst_fn
+
+    def spy(k):
+        fn = burst_fn(k)
+
+        def run(*args):
+            seen.append(args[-1])        # the device mask the burst reads
+            return fn(*args)
+        return run
+
+    eng._burst_fn = spy
+    eng.dispatch(1, mask)
+    mask[:] = False
+    assert np.asarray(seen[0]).tolist() == [True, False]
+    assert int(eng.cache["pos"][req.slot]) == 1
 
 
 def test_recycled_slot_does_not_leak_ssm_state():

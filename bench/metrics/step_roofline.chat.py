@@ -1,0 +1,3 @@
+"""`step_roofline` for the chat cell, where it moves tpot_p90_ms
+(bench/readings.py)."""
+from bench.readings import step_roofline as read  # noqa: F401

@@ -242,16 +242,23 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> Dict:
 # ---------------------------------------------------------------------------
 # Blocks (full-sequence mode)
 # ---------------------------------------------------------------------------
+def _weight(w, cfg: ModelConfig):
+    """A parameter in the compute dtype, under the ``weight_cast`` scope so
+    a device trace names the cast wherever XLA puts it (parameters stored
+    wider are cast once per program, hoisted out of the layer scan)."""
+    with jax.named_scope("weight_cast"):
+        return w.astype(cfg.compute_dtype)
+
+
 def _project_qkv(p, h_in, cfg: ModelConfig, kv_src=None):
-    cd = cfg.compute_dtype
     src = h_in if kv_src is None else kv_src
-    q = jnp.dot(h_in, p["wq"].astype(cd))
-    k = jnp.dot(src, p["wk"].astype(cd))
-    v = jnp.dot(src, p["wv"].astype(cd))
+    q = jnp.dot(h_in, _weight(p["wq"], cfg))
+    k = jnp.dot(src, _weight(p["wk"], cfg))
+    v = jnp.dot(src, _weight(p["wv"], cfg))
     if "bq" in p:
-        q = q + p["bq"].astype(cd)
-        k = k + p["bk"].astype(cd)
-        v = v + p["bv"].astype(cd)
+        q = q + _weight(p["bq"], cfg)
+        k = k + _weight(p["bk"], cfg)
+        v = v + _weight(p["bv"], cfg)
     b, s = h_in.shape[0], h_in.shape[1]
     t = src.shape[1]
     q = q.reshape(b, s, cfg.n_heads, cfg.hd).transpose(0, 2, 1, 3)
@@ -263,7 +270,7 @@ def _project_qkv(p, h_in, cfg: ModelConfig, kv_src=None):
 def _merge_heads(out, p, cfg: ModelConfig):
     b, h, s, hd = out.shape
     out = out.transpose(0, 2, 1, 3).reshape(b, s, h * hd)
-    return jnp.dot(out, p["wo"].astype(cfg.compute_dtype))
+    return jnp.dot(out, _weight(p["wo"], cfg))
 
 
 def _self_attention(p, x, cfg: ModelConfig, *, causal: bool,
@@ -301,15 +308,15 @@ def _cross_attention(p, x, kv_feats, cfg: ModelConfig, key: str = "xattn"):
 
 
 def _mlp_core(p, h_in, cfg: ModelConfig):
-    cd = cfg.compute_dtype
     if cfg.gated_mlp:
-        h = jax.nn.silu(jnp.dot(h_in, p["w_gate"].astype(cd))) * \
-            jnp.dot(h_in, p["w_up"].astype(cd))
+        h = jax.nn.silu(jnp.dot(h_in, _weight(p["w_gate"], cfg))) * \
+            jnp.dot(h_in, _weight(p["w_up"], cfg))
     else:
-        h = jax.nn.gelu(jnp.dot(h_in, p["w_up"].astype(cd)))
-    return jnp.dot(h, p["w_down"].astype(cd))
+        h = jax.nn.gelu(jnp.dot(h_in, _weight(p["w_up"], cfg)))
+    return jnp.dot(h, _weight(p["w_down"], cfg))
 
 
+@jax.named_scope("mlp")
 def _mlp(p, x, cfg: ModelConfig):
     h_in = rmsnorm(x, p["ln2"], cfg.norm_eps)
     if cfg.n_experts > 0:
@@ -493,30 +500,37 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> Dict:
 def _decode_attn_block(p, x, cache, cfg: ModelConfig, pos, cross_feats):
     """`pos` is a scalar (whole batch at one position — static batching) or a
     (B,) vector of per-slot positions (continuous batching)."""
-    h_in = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = _project_qkv(p["attn"], h_in, cfg)
-    pos_a = jnp.asarray(pos)
-    per_slot = pos_a.ndim == 1
-    posq = pos_a[:, None, None] if per_slot else jnp.full((1, 1, 1), pos_a)
-    q = apply_rope(q, posq, cfg.rope_theta)
-    k = apply_rope(k, posq, cfg.rope_theta)
-    t = cache["k"].shape[2]
-    if cfg.attn_window is not None:
-        slot = pos_a % t                    # rolling buffer
-    else:
-        slot = jnp.minimum(pos_a, t - 1)
-    # one-hot (select-based) cache write: elementwise over the time dim, so
-    # a time-SHARDED cache updates locally — dynamic_update_slice at a traced
-    # index would force GSPMD to all-gather the cache (measured: +10 GB temp
-    # per decode step on kv-unshardable archs)
-    onehot = (jnp.arange(t) == slot[..., None])  # (t,) | (B, t)
-    onehot = (onehot[:, None, :, None] if per_slot
-              else onehot[None, None, :, None])
-    k_cache = jnp.where(onehot, k.astype(cache["k"].dtype), cache["k"])
-    v_cache = jnp.where(onehot, v.astype(cache["v"].dtype), cache["v"])
-    out = attn_lib.decode_attention(q, k_cache, v_cache, pos=pos,
-                                    window=cfg.attn_window)
-    x = x + _merge_heads(out, p["attn"], cfg)
+    with jax.named_scope("attention"):
+        h_in = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        q, k, v = _project_qkv(p["attn"], h_in, cfg)
+        pos_a = jnp.asarray(pos)
+        per_slot = pos_a.ndim == 1
+        posq = (pos_a[:, None, None] if per_slot
+                else jnp.full((1, 1, 1), pos_a))
+        q = apply_rope(q, posq, cfg.rope_theta)
+        k = apply_rope(k, posq, cfg.rope_theta)
+        t = cache["k"].shape[2]
+        if cfg.attn_window is not None:
+            slot = pos_a % t                    # rolling buffer
+        else:
+            slot = jnp.minimum(pos_a, t - 1)
+        with jax.named_scope("kv_write"):
+            # one-hot (select-based) cache write: elementwise over the time
+            # dim, so a time-SHARDED cache updates locally —
+            # dynamic_update_slice at a traced index would force GSPMD to
+            # all-gather the cache (measured: +10 GB temp per decode step
+            # on kv-unshardable archs)
+            onehot = (jnp.arange(t) == slot[..., None])  # (t,) | (B, t)
+            onehot = (onehot[:, None, :, None] if per_slot
+                      else onehot[None, None, :, None])
+            k_cache = jnp.where(onehot, k.astype(cache["k"].dtype),
+                                cache["k"])
+            v_cache = jnp.where(onehot, v.astype(cache["v"].dtype),
+                                cache["v"])
+        with jax.named_scope("core"):
+            out = attn_lib.decode_attention(q, k_cache, v_cache, pos=pos,
+                                            window=cfg.attn_window)
+        x = x + _merge_heads(out, p["attn"], cfg)
     if "xattn" in p and cross_feats is not None:
         x = x + _cross_attention(p, x, cross_feats, cfg)
     x = _mlp(p, x, cfg)
@@ -586,8 +600,10 @@ def _decode_step_impl(params, cfg: ModelConfig, cache: Dict,
                     active.reshape((b,) + (1,) * (n.ndim - 1)), n, o)
             return jax.tree.map(sel, new, old)
 
-    x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.compute_dtype)
-    x = _constrain_act(x, cfg)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens,
+                     axis=0).astype(cfg.compute_dtype)
+        x = _constrain_act(x, cfg)
 
     blocks_cache, rem_cache = cache["layers"]
 
@@ -602,19 +618,24 @@ def _decode_step_impl(params, cfg: ModelConfig, cache: Dict,
 
     new_blocks = blocks_cache
     if cfg.n_super > 0:
-        x, new_blocks = jax.lax.scan(
-            super_block, x, (params["decoder"]["blocks"], blocks_cache))
+        # the scan's own work (each layer's parameters and cache sliced out
+        # of the stacked arrays, its new cache written back) is named
+        # ``layer_scan``; the blocks inside carry their own scopes
+        with jax.named_scope("layer_scan"):
+            x, new_blocks = jax.lax.scan(
+                super_block, x, (params["decoder"]["blocks"], blocks_cache))
     new_rem = []
     for i, p in enumerate(params["decoder"]["rem"]):
         btype = unit[i % len(unit)]
         x, nc = block_step(btype, p, x, rem_cache[i])
         new_rem.append(keep(nc, rem_cache[i]))
 
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"].T
-    logits = jnp.dot(x, head.astype(cfg.compute_dtype))
+    with jax.named_scope("head"):
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        head = params.get("lm_head")
+        if head is None:
+            head = params["embed"].T
+        logits = jnp.dot(x, _weight(head, cfg))
     # copy-and-update: layout-specific keys (e.g. paged block_tables)
     # survive; the dense cache carries exactly layers/pos/cross either way
     new_cache = dict(cache)
@@ -757,32 +778,36 @@ def _decode_attn_block_paged(p, x, cache, cfg: ModelConfig, pos,
     the block table.  Inactive slots' writes route to the trash page, so
     live pages are never clobbered (the dense path's `keep` select has no
     slot-major arena axis to apply to)."""
-    h_in = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = _project_qkv(p["attn"], h_in, cfg)
-    pos_a = jnp.asarray(pos)
-    assert pos_a.ndim == 1, "paged decode is per-slot (continuous batching)"
-    posq = pos_a[:, None, None]
-    q = apply_rope(q, posq, cfg.rope_theta)
-    k = apply_rope(k, posq, cfg.rope_theta)
+    with jax.named_scope("attention"):
+        h_in = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        q, k, v = _project_qkv(p["attn"], h_in, cfg)
+        pos_a = jnp.asarray(pos)
+        assert pos_a.ndim == 1, \
+            "paged decode is per-slot (continuous batching)"
+        posq = pos_a[:, None, None]
+        q = apply_rope(q, posq, cfg.rope_theta)
+        k = apply_rope(k, posq, cfg.rope_theta)
 
-    b = x.shape[0]
-    bs = cache["k"].shape[-2]
-    nb = block_tables.shape[1]
-    trash = cache["k"].shape[0] - 1
-    j = jnp.clip(pos_a // bs, 0, nb - 1)
-    off = pos_a % bs
-    phys = block_tables[jnp.arange(b), j]
-    phys = jnp.where(active, phys, trash)
-    heads = jnp.arange(cfg.n_kv_heads)[None, :]
-    k_arena = cache["k"].at[phys[:, None], heads, off[:, None]].set(
-        k[:, :, 0, :].astype(cache["k"].dtype))
-    v_arena = cache["v"].at[phys[:, None], heads, off[:, None]].set(
-        v[:, :, 0, :].astype(cache["v"].dtype))
+        with jax.named_scope("kv_write"):
+            b = x.shape[0]
+            bs = cache["k"].shape[-2]
+            nb = block_tables.shape[1]
+            trash = cache["k"].shape[0] - 1
+            j = jnp.clip(pos_a // bs, 0, nb - 1)
+            off = pos_a % bs
+            phys = block_tables[jnp.arange(b), j]
+            phys = jnp.where(active, phys, trash)
+            heads = jnp.arange(cfg.n_kv_heads)[None, :]
+            k_arena = cache["k"].at[phys[:, None], heads, off[:, None]].set(
+                k[:, :, 0, :].astype(cache["k"].dtype))
+            v_arena = cache["v"].at[phys[:, None], heads, off[:, None]].set(
+                v[:, :, 0, :].astype(cache["v"].dtype))
 
-    out = attn_lib.paged_decode_attention(
-        q, k_arena, v_arena, block_tables, pos_a, max_seq=max_seq,
-        impl=cfg.paged_attention_impl)
-    x = x + _merge_heads(out, p["attn"], cfg)
+        with jax.named_scope("core"):
+            out = attn_lib.paged_decode_attention(
+                q, k_arena, v_arena, block_tables, pos_a, max_seq=max_seq,
+                impl=cfg.paged_attention_impl)
+        x = x + _merge_heads(out, p["attn"], cfg)
     if "xattn" in p and cross_feats is not None:
         x = x + _cross_attention(p, x, cross_feats, cfg)
     x = _mlp(p, x, cfg)
@@ -829,34 +854,40 @@ def _multi_attn_block_paged(p, x, cache, cfg: ModelConfig, pos,
     steps would see it).  Inactive slots' writes route to the trash page;
     their offsets collide across slots there, which is harmless — the
     trash page is never attended."""
-    h_in = rmsnorm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = _project_qkv(p["attn"], h_in, cfg)             # (B,H,M,hd)
-    pos_a = jnp.asarray(pos)
-    assert pos_a.ndim == 1, "paged decode is per-slot (continuous batching)"
-    b, m = x.shape[0], x.shape[1]
-    wpos = pos_a[:, None] + jnp.arange(m)                    # (B, M)
-    posq = wpos[:, None, :]
-    q = apply_rope(q, posq, cfg.rope_theta)
-    k = apply_rope(k, posq, cfg.rope_theta)
+    with jax.named_scope("attention"):
+        h_in = rmsnorm(x, p["ln1"], cfg.norm_eps)
+        q, k, v = _project_qkv(p["attn"], h_in, cfg)         # (B,H,M,hd)
+        pos_a = jnp.asarray(pos)
+        assert pos_a.ndim == 1, \
+            "paged decode is per-slot (continuous batching)"
+        b, m = x.shape[0], x.shape[1]
+        wpos = pos_a[:, None] + jnp.arange(m)                # (B, M)
+        posq = wpos[:, None, :]
+        q = apply_rope(q, posq, cfg.rope_theta)
+        k = apply_rope(k, posq, cfg.rope_theta)
 
-    bs = cache["k"].shape[-2]
-    nb = block_tables.shape[1]
-    trash = cache["k"].shape[0] - 1
-    j = jnp.clip(wpos // bs, 0, nb - 1)
-    off = wpos % bs
-    phys = jnp.take_along_axis(block_tables, j, axis=1)      # (B, M)
-    phys = jnp.where(active[:, None], phys, trash)
-    heads = jnp.arange(cfg.n_kv_heads)[None, None, :]
-    # window positions are distinct per slot and slots own disjoint pages,
-    # so the M-way scatter has no live-page collisions
-    k_arena = cache["k"].at[phys[:, :, None], heads, off[:, :, None]].set(
-        k.transpose(0, 2, 1, 3).astype(cache["k"].dtype))
-    v_arena = cache["v"].at[phys[:, :, None], heads, off[:, :, None]].set(
-        v.transpose(0, 2, 1, 3).astype(cache["v"].dtype))
+        with jax.named_scope("kv_write"):
+            bs = cache["k"].shape[-2]
+            nb = block_tables.shape[1]
+            trash = cache["k"].shape[0] - 1
+            j = jnp.clip(wpos // bs, 0, nb - 1)
+            off = wpos % bs
+            phys = jnp.take_along_axis(block_tables, j, axis=1)  # (B, M)
+            phys = jnp.where(active[:, None], phys, trash)
+            heads = jnp.arange(cfg.n_kv_heads)[None, None, :]
+            # window positions are distinct per slot and slots own disjoint
+            # pages, so the M-way scatter has no live-page collisions
+            k_arena = cache["k"].at[phys[:, :, None], heads,
+                                    off[:, :, None]].set(
+                k.transpose(0, 2, 1, 3).astype(cache["k"].dtype))
+            v_arena = cache["v"].at[phys[:, :, None], heads,
+                                    off[:, :, None]].set(
+                v.transpose(0, 2, 1, 3).astype(cache["v"].dtype))
 
-    out = attn_lib.paged_decode_attention_multi(
-        q, k_arena, v_arena, block_tables, pos_a, max_seq=max_seq)
-    x = x + _merge_heads(out, p["attn"], cfg)
+        with jax.named_scope("core"):
+            out = attn_lib.paged_decode_attention_multi(
+                q, k_arena, v_arena, block_tables, pos_a, max_seq=max_seq)
+        x = x + _merge_heads(out, p["attn"], cfg)
     if "xattn" in p and cross_feats is not None:
         x = x + _cross_attention(p, x, cross_feats, cfg)
     x = _mlp(p, x, cfg)

@@ -9,8 +9,9 @@ bursts.  This module is the measurement substrate: a tracer that records
   * **spans** — named intervals on a (track, tid) pair.  Request lifecycle
     spans live on the ``requests`` track with ``tid = rid`` (``queued``,
     ``prefill``, ``handoff``, ``decode``); engine-level spans live on one
-    track per :class:`~repro.serving.engine_loop.SlotEngine` (``burst``
-    dispatches, ``sync`` host waits).
+    track per :class:`~repro.serving.engine_loop.SlotEngine` (``admit``,
+    ``burst`` dispatches, ``sync`` host waits, ``emit`` delta emission),
+    and the driver's ``iteration`` spans on the ``server`` track.
   * **instants** — point events (``first_token`` host visibility,
     ``kv_alloc``/``kv_free`` block-lease events, ``done``/``dropped``).
   * **counters** — sampled value series (KV occupancy, queue depth) that
@@ -22,6 +23,13 @@ clock (``now_fn - t0 + idle fast-forward``) at run start, so every trace
 timestamp lives on the same offered-load timeline as the serving metrics
 (TTFT, latency).  Tests inject deterministic clocks and get golden traces.
 
+Live spans — those that open and close around the instrumented code in
+one call, via :meth:`Tracer.scope` — are also written into any running
+``jax.profiler`` session as a ``TraceAnnotation`` with the span's args,
+so the program's own spans land in the device trace (``.xplane.pb``) on
+the device's clock, beside the operations they launched.  With no
+session running, that costs about a microsecond a span.
+
 Cost discipline: events append to a bounded ring buffer (old events drop,
 ``n_dropped`` counts them — a long-lived server never grows without bound),
 and :class:`NullTracer` implements the same surface as no-ops with
@@ -29,11 +37,13 @@ and :class:`NullTracer` implements the same surface as no-ops with
 construction and tracing-off stays near-zero cost.
 
 The Chrome-trace/Perfetto JSON serialization lives in
-:mod:`repro.obs.export`; this module is dependency-free (no jax) so the
-launch CLIs can import its clock before touching jax.
+:mod:`repro.obs.export`; this module imports no jax (``scope`` imports
+the profiler when a live span first runs) so the launch CLIs can import
+its clock before touching jax.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -146,6 +156,28 @@ class Tracer:
                               dur=max(t1 - t0, 0.0), pid=pid, tid=tid,
                               cat=cat, args=a0))
 
+    @contextlib.contextmanager
+    def scope(self, name: str, *, track: str, tid: int = 0,
+              cat: str = "span", args: Optional[dict] = None):
+        """A live span around the ``with`` body: recorded in the ring as
+        :meth:`begin`/:meth:`end` record it, and wrapped in a
+        ``jax.profiler.TraceAnnotation`` of the same name and args, so a
+        running profiler session holds it on the device trace's clock.
+        The body gets ``note(**args)`` for args known only at its end;
+        they reach both records.  Spans that end in a later iteration (a
+        hand-off) keep to begin/end."""
+        from jax.profiler import TraceAnnotation    # obs stays jax-free
+        h = self.begin(name, track=track, tid=tid, cat=cat, args=args)
+        late: dict = {}
+        try:
+            with TraceAnnotation(name, **(args or {})) as annotation:
+                def note(**kw) -> None:
+                    late.update(kw)
+                    annotation.set_metadata(**kw)
+                yield note
+        finally:
+            self.end(h, args=late)
+
     @property
     def n_open(self) -> int:
         """Spans begun but not yet ended (0 after a well-formed run)."""
@@ -175,6 +207,10 @@ class Tracer:
 
     def __len__(self) -> int:
         return len(self.events)
+
+
+# one shared no-op scope; its ``note`` discards late args
+_NULL_SCOPE = contextlib.nullcontext(lambda **kw: None)
 
 
 class NullTracer:
@@ -213,6 +249,9 @@ class NullTracer:
 
     def end(self, *a, **k) -> None:
         pass
+
+    def scope(self, *a, **k):
+        return _NULL_SCOPE
 
     def instant(self, *a, **k) -> None:
         pass

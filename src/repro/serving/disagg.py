@@ -67,9 +67,10 @@ from ..obs import MetricsRegistry, Observability, default_clock
 from .batcher import ContinuousBatcher
 from .driver import (OpenLoopDriver, ServeMetrics, StreamDelta, TokenSink,
                      burst_size, sample_pools)
-from .engine_loop import (SlotEngine, snapshot_ready, snapshot_wait,
-                          state_to_device, trace_admission, trace_completion,
-                          trace_phase_flip, wire_pool_events)
+from .engine_loop import (SlotEngine, count_burst, snapshot_ready,
+                          snapshot_wait, state_to_device, trace_admission,
+                          trace_completion, trace_phase_flip,
+                          wire_pool_events)
 from .kv_pool import KVPool
 from .request import Request, RequestState
 from .speculative import SpecPlan, SpeculativeDecoder, spec_dispatch
@@ -590,11 +591,6 @@ class DisaggregatedEngineLoop:
                 budget=None if budget is None else budget - n)
             if burst > 0:
                 n_burst = int(mask.sum())
-                h = (tracer.begin("burst", track=f"engine:{eng.name}",
-                                  cat="engine",
-                                  args={"steps": burst,
-                                        "n_active": n_burst})
-                     if tracer.enabled else None)
                 # only decode bursts feed the cache: they run the per-token
                 # decode network admission prices; prefill bursts do too
                 # mathematically, but attributing them to the decode batch
@@ -603,20 +599,23 @@ class DisaggregatedEngineLoop:
                 # (engine, phase) batcher pricing, so there is no mixing
                 feed = fb is not None and eng is self.decode
                 timed = feed or wd is not None
-                t0 = tracer.now() if timed else 0.0
-                eng.dispatch(burst, mask)
-                if timed:
-                    eng.sync()
-                    dt = tracer.now() - t0
-                    if feed:
-                        fb.observe_burst(n_burst, burst, dt)
-                    if wd is not None:
-                        wd.observe_burst(
-                            eng.name, batcher.phase, n_tokens=n_burst,
-                            steps=burst, elapsed_s=dt,
-                            priced_step_s=batcher.priced_step_s(n_burst))
-                if h is not None:
-                    tracer.end(h, args={"synced": timed})
+                args = count_burst(self.obs, eng, burst, mask)
+                with tracer.scope("burst", track=f"engine:{eng.name}",
+                                  cat="engine",
+                                  args={**args, "synced": timed}):
+                    t0 = tracer.now() if timed else 0.0
+                    eng.dispatch(burst, mask)
+                    if timed:
+                        eng.sync()
+                        dt = tracer.now() - t0
+                        if feed:
+                            fb.observe_burst(n_burst, burst, dt)
+                        if wd is not None:
+                            wd.observe_burst(
+                                eng.name, batcher.phase, n_tokens=n_burst,
+                                steps=burst, elapsed_s=dt,
+                                priced_step_s=batcher.priced_step_s(
+                                    n_burst))
                 n += burst
         return n
 
@@ -784,13 +783,10 @@ class DisaggregatedEngineLoop:
                         or req.rid in ready_rids):
                     continue
                 if eng.steps_done[s] >= eng.steps_total[s]:
-                    h = (tracer.begin("sync", track=f"engine:{eng.name}",
+                    with tracer.scope("sync", track=f"engine:{eng.name}",
                                       cat="engine",
-                                      args={"kind": "completion"})
-                         if tracer.enabled else None)
-                    row = eng.pull_output(s)
-                    if h is not None:
-                        tracer.end(h)
+                                      args={"kind": "completion"}):
+                        row = eng.pull_output(s)
                     req.state = RequestState.DONE
                     req.t_done = clock()
                     sink.finish(req, row[:req.max_new_tokens], req.t_done)

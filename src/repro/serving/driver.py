@@ -45,8 +45,9 @@ rows (asserted in tests/test_driver.py and benchmarks/bench_serving.py).
 
 Observability: the driver reads the loop's :class:`~repro.obs.Observability`
 bundle.  It installs the skew clock into the tracer at run start (every
-trace timestamp lives on the offered-load timeline the metrics use),
-refreshes the registry's gauges each iteration (KV occupancy, queue depth,
+trace timestamp lives on the offered-load timeline the metrics use), wraps
+each loop pass in an ``iteration`` span, refreshes the registry's gauges
+each iteration (KV occupancy, reserved but unwritten KV, queue depth,
 in-flight slots, admission totals) and samples them into the registry's
 time series, and mirrors the same values as Perfetto counter tracks when
 tracing is on.  ``ServeMetrics`` mirrors its per-request observations into
@@ -202,19 +203,21 @@ class TokenSink:
         if n % max(self.sync_every, 1) != 0:
             return                       # skipped boundary: tokens ride the
             #                              next drain (or the finish() tail)
-        h = (self.tracer.begin("sync", track=f"engine:{engine.name}",
-                               cat="engine", args={"kind": "drain"})
-             if self.tracer.enabled else None)
-        t0 = self.tracer.now() if self.watchdog is not None else 0.0
-        rows = engine.pull_outputs()     # host sync: burst results land
-        if self.watchdog is not None:
-            self.watchdog.observe_sync(self.tracer.now() - t0)
-        if h is not None:
-            self.tracer.end(h)
+        tracer, track = self.tracer, f"engine:{engine.name}"
+        with tracer.scope("sync", track=track, cat="engine",
+                          args={"kind": "drain"}):
+            t0 = tracer.now() if self.watchdog is not None else 0.0
+            rows = engine.pull_outputs()  # host sync: burst results land
+            if self.watchdog is not None:
+                self.watchdog.observe_sync(tracer.now() - t0)
         t = clock()                      # stamped AFTER materialization
-        for s, req in enumerate(engine.slots):
-            if req is not None:
-                self._emit(req, rows[s], req.samples_ready, t, done=False)
+        with tracer.scope("emit", track=track, cat="engine") as note:
+            deltas = 0
+            for s, req in enumerate(engine.slots):
+                if req is not None:
+                    deltas += self._emit(req, rows[s], req.samples_ready, t,
+                                         done=False)
+            note(deltas=deltas)
 
     def finish(self, req: Request, row: np.ndarray, t: float) -> None:
         """Completion pull: install the final output row (and stream the
@@ -236,11 +239,13 @@ class TokenSink:
                                       "ttft_dispatch_s": req.ttft_dispatch})
 
     def _emit(self, req: Request, row: np.ndarray, n_ready: int, t: float,
-              done: bool) -> None:
+              done: bool) -> bool:
+        """Emit the request's newly readable samples; True if a delta went
+        out."""
         new = ([] if n_ready <= req.n_streamed
                else [int(x) for x in row[req.n_streamed:n_ready]])
         if not new and not done:
-            return
+            return False
         if new and req.t_first_token is None:
             req.t_first_token = t        # first sample host-visible
             self._first_token_instant(req, t)
@@ -248,6 +253,7 @@ class TokenSink:
         self.metrics.tokens_streamed += len(new)
         self.metrics.n_stream_deltas += 1
         self.on_delta(StreamDelta(rid=req.rid, tokens=new, t=t, done=done))
+        return True
 
 
 def burst_size(remaining: int, *, throttle: bool,
@@ -262,6 +268,16 @@ def burst_size(remaining: int, *, throttle: bool,
     if budget is not None:
         burst = min(burst, max(budget, 0))
     return burst
+
+
+def reserved_unused(pools) -> float:
+    """Leased but unwritten token capacity over the pools' whole capacity:
+    blocks a request reserved at admission for prompt and output tokens it
+    has not written yet."""
+    cap = sum(p.total_blocks * p.block_size for p in pools)
+    unused = sum(p.allocated_block_count * p.block_size - p.written_tokens
+                 for p in pools)
+    return unused / cap if cap else 0.0
 
 
 def sample_pools(pools) -> tuple:
@@ -341,17 +357,19 @@ class OpenLoopDriver:
                 # latency remain >= 0)
                 skew += pending[0].arrival - now
                 continue
-            loop.admit(queue, now, metrics)
-            if not loop.runnable():
-                continue                 # nothing admissible (pool pressure)
-            throttle = bool(pending) or loop.backlogged(queue)
-            budget = (None if max_steps is None
-                      else max_steps - metrics.n_steps)
-            metrics.n_steps += loop.dispatch(throttle, budget)
-            loop.sample(metrics)
-            loop.scan(clock, metrics, sink)
-            self._act_on_watchdog(sink)
-            self._observe_iteration(metrics, queue, pending, clock())
+            with obs.tracer.scope("iteration", track="server",
+                                  args={"queue_depth": len(queue)}):
+                loop.admit(queue, now, metrics)
+                if not loop.runnable():
+                    continue             # nothing admissible (pool pressure)
+                throttle = bool(pending) or loop.backlogged(queue)
+                budget = (None if max_steps is None
+                          else max_steps - metrics.n_steps)
+                metrics.n_steps += loop.dispatch(throttle, budget)
+                loop.sample(metrics)
+                loop.scan(clock, metrics, sink)
+                self._act_on_watchdog(sink)
+                self._observe_iteration(metrics, queue, pending, clock())
             if max_steps is not None and metrics.n_steps >= max_steps:
                 break
         metrics.elapsed_s = clock()
@@ -380,7 +398,9 @@ class OpenLoopDriver:
         occ = metrics.occupancy[-1] if metrics.occupancy else 0.0
         util = metrics.utilization[-1] if metrics.utilization else 0.0
         in_flight = loop.n_active
+        unused = reserved_unused([b.pool for b in loop.batchers])
         reg.gauge("kv_occupancy").set(occ)
+        reg.gauge("kv_reserved_unused").set(unused)
         reg.gauge("kv_utilization").set(util)
         reg.gauge("queue_depth").set(len(queue))
         reg.gauge("pending_arrivals").set(len(pending))
@@ -393,7 +413,8 @@ class OpenLoopDriver:
         reg.sample(now)
         tracer = self.obs.tracer
         if tracer.enabled:
-            tracer.counter("kv", {"occupancy": occ, "utilization": util},
+            tracer.counter("kv", {"occupancy": occ, "utilization": util,
+                                  "reserved_unused": unused},
                            track="server", t=now)
             tracer.counter("load", {"queue_depth": len(queue),
                                     "pending_arrivals": len(pending),

@@ -118,6 +118,22 @@ def trace_completion(tracer, req) -> None:
                                        else req.t_done - req.arrival)})
 
 
+def count_burst(obs, eng: "SlotEngine", burst: int,
+                mask: np.ndarray) -> Dict[str, int]:
+    """Add a burst's prompt and output tokens (before it is dispatched) to
+    the registry's engine counters; returns them as the ``burst`` span's
+    args."""
+    prefill_tokens, prefill_steps, decode_tokens = eng.token_split(burst,
+                                                                   mask)
+    reg = obs.registry
+    reg.counter("engine_prefill_tokens").inc(prefill_tokens)
+    reg.counter("engine_prefill_steps").inc(prefill_steps)
+    reg.counter("engine_decode_tokens").inc(decode_tokens)
+    return {"steps": burst, "n_active": int(mask.sum()),
+            "prefill_tokens": prefill_tokens,
+            "prefill_steps": prefill_steps, "decode_tokens": decode_tokens}
+
+
 def _fused_step(step_fn, params, cfg, cache, prompts, plens, last_tok,
                 out_buf, active):
     """Device-side feed + step + sample + output scatter.
@@ -130,19 +146,23 @@ def _fused_step(step_fn, params, cfg, cache, prompts, plens, last_tok,
     outputs."""
     b = prompts.shape[0]
     pos = cache["pos"]
-    prompt_tok = prompts[jnp.arange(b), jnp.minimum(pos, prompts.shape[1] - 1)]
-    tok = jnp.where(pos < plens, prompt_tok, last_tok)
+    with jax.named_scope("feed_sample"):
+        prompt_tok = prompts[jnp.arange(b),
+                             jnp.minimum(pos, prompts.shape[1] - 1)]
+        tok = jnp.where(pos < plens, prompt_tok, last_tok)
     logits, cache = step_fn(params, cfg, cache, tok[:, None], active)
-    nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
-    # the sample is output index (pos - plen + 1); valid once the final
-    # prompt token has been fed (same schedule as the static replay path)
-    idx = pos - plens + 1
-    write = active & (idx >= 0) & (idx < out_buf.shape[1])
-    safe_idx = jnp.clip(idx, 0, out_buf.shape[1] - 1)
-    row = out_buf[jnp.arange(b), safe_idx]
-    out_buf = out_buf.at[jnp.arange(b), safe_idx].set(
-        jnp.where(write, nxt, row))
-    last_tok = jnp.where(active, nxt, last_tok)
+    with jax.named_scope("feed_sample"):
+        nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+        # the sample is output index (pos - plen + 1); valid once the final
+        # prompt token has been fed (same schedule as the static replay
+        # path)
+        idx = pos - plens + 1
+        write = active & (idx >= 0) & (idx < out_buf.shape[1])
+        safe_idx = jnp.clip(idx, 0, out_buf.shape[1] - 1)
+        row = out_buf[jnp.arange(b), safe_idx]
+        out_buf = out_buf.at[jnp.arange(b), safe_idx].set(
+            jnp.where(write, nxt, row))
+        last_tok = jnp.where(active, nxt, last_tok)
     return cache, last_tok, out_buf
 
 
@@ -214,10 +234,12 @@ class SlotEngine:
                                 self._out_buf), device)
         self._burst_fns: Dict[int, Callable] = {}
         self.slots: List[Optional[Request]] = [None] * n_slots
-        # host-side schedule state: active steps done / total per slot, plus
-        # the dispatch mask (bind sets, release clears)
+        # host-side schedule state: active steps done / total per slot, the
+        # prompt steps each slot has left, plus the dispatch mask (bind
+        # sets, release clears)
         self.steps_done = np.zeros((n_slots,), np.int64)
         self.steps_total = np.zeros((n_slots,), np.int64)
+        self.prompt_left = np.zeros((n_slots,), np.int64)
         self.active = np.zeros((n_slots,), bool)
 
     def _burst_fn(self, k: int) -> Callable:
@@ -324,7 +346,19 @@ class SlotEngine:
         self.slots[s] = req
         self.steps_done[s] = 0
         self.steps_total[s] = steps_total
+        self.prompt_left[s] = req.prompt_len - start_pos
         self.active[s] = True
+
+    def token_split(self, burst: int, mask: np.ndarray) -> tuple:
+        """``(prefill_tokens, prefill_steps, decode_tokens)`` of a burst of
+        ``burst`` steps over the ``mask`` slots: a slot with ``r`` prompt
+        steps left feeds ``min(r, burst)`` prompt tokens and the rest of
+        its steps output tokens; ``prefill_steps`` is how many of the
+        burst's steps carry any prompt token."""
+        fed = np.minimum(self.prompt_left[mask], burst)
+        prefill = int(fed.sum())
+        return (prefill, int(fed.max(initial=0)),
+                burst * int(mask.sum()) - prefill)
 
     def dispatch(self, burst: int, active_np: np.ndarray) -> None:
         """Dispatch `burst` fused steps over the active slots (bucketed
@@ -343,6 +377,8 @@ class SlotEngine:
                 self._last_tok, self._out_buf, active_dev)
             k -= b
         self.steps_done[active_np] += burst
+        self.prompt_left[active_np] = np.maximum(
+            self.prompt_left[active_np] - burst, 0)
         for s, req in enumerate(self.slots):
             if req is not None and active_np[s]:
                 self.pool.note_write(req.rid, burst)
@@ -539,6 +575,7 @@ class SlotEngine:
         self.slots[s] = req
         self.steps_done[s] = 0
         self.steps_total[s] = steps_total
+        self.prompt_left[s] = max(req.prompt_len - req.n_fed, 0)
         self.active[s] = True
 
     @staticmethod
@@ -663,19 +700,23 @@ class EngineLoop:
 
     def admit(self, queue: List[Request], now: float,
               metrics: ServeMetrics) -> None:
-        decision = self.batcher.admit(queue, self.engine.n_active, now)
-        metrics.drop(len(decision.dropped))
-        for req in decision.admitted:
-            # greedy decoding with known lengths: completion is
-            # deterministic — the final sample lands after
-            # plen + gen - 1 active steps (minus any prefix-shared
-            # tokens, whose prefill is skipped by binding at an offset)
-            shared = self.pool.shared_tokens(req.rid)
-            req.shared_tokens = shared
-            self.engine.bind(
-                req, start_pos=shared,
-                steps_total=(req.prompt_len - shared
-                             + req.max_new_tokens - 1))
+        track = f"engine:{self.engine.name}"
+        with self.obs.tracer.scope("admit", track=track,
+                                   cat="engine") as note:
+            decision = self.batcher.admit(queue, self.engine.n_active, now)
+            metrics.drop(len(decision.dropped))
+            for req in decision.admitted:
+                # greedy decoding with known lengths: completion is
+                # deterministic — the final sample lands after
+                # plen + gen - 1 active steps (minus any prefix-shared
+                # tokens, whose prefill is skipped by binding at an offset)
+                shared = self.pool.shared_tokens(req.rid)
+                req.shared_tokens = shared
+                self.engine.bind(
+                    req, start_pos=shared,
+                    steps_total=(req.prompt_len - shared
+                                 + req.max_new_tokens - 1))
+            note(admitted=len(decision.admitted))
         trace_admission(self.obs, self.batcher, decision,
                         self.engine.n_active)
         return decision
@@ -691,27 +732,25 @@ class EngineLoop:
             return 0
         tracer, fb, wd = self.obs.tracer, self.obs.feedback, self.obs.watchdog
         n_active = eng.n_active
-        h = (tracer.begin("burst", track=f"engine:{eng.name}", cat="engine",
-                          args={"steps": burst, "n_active": n_active})
-             if tracer.enabled else None)
         timed = fb is not None or wd is not None
-        t0 = tracer.now() if timed else 0.0
-        eng.dispatch(burst, eng.active)
-        if timed:
-            # telemetry feedback / the watchdog want device wall time per
-            # step, so wait for the burst (a pure wait: outputs stay
-            # bit-identical)
-            eng.sync()
-            dt = tracer.now() - t0
-            if fb is not None:
-                fb.observe_burst(n_active, burst, dt)
-            if wd is not None:
-                wd.observe_burst(
-                    eng.name, self.batcher.phase, n_tokens=n_active,
-                    steps=burst, elapsed_s=dt,
-                    priced_step_s=self.batcher.priced_step_s(n_active))
-        if h is not None:
-            tracer.end(h, args={"synced": timed})
+        args = count_burst(self.obs, eng, burst, eng.active)
+        with tracer.scope("burst", track=f"engine:{eng.name}", cat="engine",
+                          args={**args, "synced": timed}):
+            t0 = tracer.now() if timed else 0.0
+            eng.dispatch(burst, eng.active)
+            if timed:
+                # telemetry feedback / the watchdog want device wall time
+                # per step, so wait for the burst (a pure wait: outputs
+                # stay bit-identical)
+                eng.sync()
+                dt = tracer.now() - t0
+                if fb is not None:
+                    fb.observe_burst(n_active, burst, dt)
+                if wd is not None:
+                    wd.observe_burst(
+                        eng.name, self.batcher.phase, n_tokens=n_active,
+                        steps=burst, elapsed_s=dt,
+                        priced_step_s=self.batcher.priced_step_s(n_active))
         return burst
 
     def on_drift(self, alert, watchdog) -> None:
@@ -760,12 +799,9 @@ class EngineLoop:
                 continue
             if eng.steps_done[s] >= eng.steps_total[s]:
                 # completion boundary: sync and pull this slot's tokens
-                h = (tracer.begin("sync", track=f"engine:{eng.name}",
-                                  cat="engine", args={"kind": "completion"})
-                     if tracer.enabled else None)
-                row = eng.pull_output(s)
-                if h is not None:
-                    tracer.end(h)
+                with tracer.scope("sync", track=f"engine:{eng.name}",
+                                  cat="engine", args={"kind": "completion"}):
+                    row = eng.pull_output(s)
                 req.state = RequestState.DONE
                 req.t_done = clock()
                 sink.finish(req, row[:req.max_new_tokens], req.t_done)

@@ -402,33 +402,36 @@ def spec_dispatch(spec: SpeculativeDecoder, eng, pool, batcher, obs, *,
     tracer, fb, wd = obs.tracer, obs.feedback, obs.watchdog
     spec.sync_drafts(pos, mask)
     n_active = int(mask.sum())
-    h = (tracer.begin("burst", track=f"engine:{eng.name}", cat="engine",
-                      args={"steps": spec.plan.k + 1, "n_active": n_active,
-                            "speculative": True})
-         if tracer.enabled else None)
     timed = fb is not None or wd is not None
-    t0 = tracer.now() if timed else 0.0
-    c = spec.round(mask, rem)
-    committed = int(c[mask].sum())
-    eng.steps_done[mask] += c[mask]
-    for s in np.flatnonzero(mask):
-        req = eng.slots[s]
-        if req is not None and c[s] > 0:
-            pool.note_write(req.rid, int(c[s]))
-    if timed:
-        eng.sync()
-        dt = tracer.now() - t0
-        # per-slot committed tokens this round, as fractional "steps": the
-        # watchdog/feedback contract is wall time per step per token
-        steps = committed / max(n_active, 1)
-        if fb is not None:
-            fb.observe_burst(n_active, steps, dt)
-        if wd is not None:
-            wd.observe_burst(eng.name, batcher.phase, n_tokens=n_active,
-                             steps=steps, elapsed_s=dt,
-                             priced_step_s=batcher.priced_step_s(n_active))
-    if h is not None:
-        tracer.end(h, args={"synced": timed, "committed": committed})
+    # a round decodes only: every committed token is an output token
+    with tracer.scope("burst", track=f"engine:{eng.name}", cat="engine",
+                      args={"steps": spec.plan.k + 1, "n_active": n_active,
+                            "speculative": True, "prefill_tokens": 0,
+                            "prefill_steps": 0, "synced": timed}) as note:
+        t0 = tracer.now() if timed else 0.0
+        c = spec.round(mask, rem)
+        committed = int(c[mask].sum())
+        eng.steps_done[mask] += c[mask]
+        for s in np.flatnonzero(mask):
+            req = eng.slots[s]
+            if req is not None and c[s] > 0:
+                pool.note_write(req.rid, int(c[s]))
+        if timed:
+            eng.sync()
+            dt = tracer.now() - t0
+            # per-slot committed tokens this round, as fractional "steps":
+            # the watchdog/feedback contract is wall time per step per
+            # token
+            steps = committed / max(n_active, 1)
+            if fb is not None:
+                fb.observe_burst(n_active, steps, dt)
+            if wd is not None:
+                wd.observe_burst(
+                    eng.name, batcher.phase, n_tokens=n_active,
+                    steps=steps, elapsed_s=dt,
+                    priced_step_s=batcher.priced_step_s(n_active))
+        note(committed=committed, decode_tokens=committed)
+    obs.registry.counter("engine_decode_tokens").inc(committed)
     if spec.take_veto():
         # measured acceptance re-priced speculation worse than plain
         # decode: admission returns to the analytic plain-step model

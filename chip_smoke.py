@@ -10,7 +10,8 @@ One chip, in order:
    a pricing model (``core.device_models.BY_DEVICE_KIND``);
 2. kernels: every Pallas kernel of the two paths, compiled for the chip
    (``interpret=False``), against its ``kernels/ref.py`` oracle, at the
-   AlexNet (``alexnet_full_spec``) and qwen2_1_5b widths;
+   AlexNet (``alexnet_full_spec``) and qwen2_1_5b widths, and paged decode
+   attention at both benchmark cells' bf16 serving shapes;
 3. CNNLab: the AlexNet plan with every layer on ``xla``, every layer on
    ``pallas``, and as ``scheduler.schedule`` assigns it over
    ``DEFAULT_ENGINES``, each compiled with ``plan.compile_plan`` and run on
@@ -48,6 +49,8 @@ SERVE = dict(arch="qwen2_1_5b", scale="full", requests=8, slots=4,
 # scaled so every output is O(1) (LRN: O(10), where its window term
 # matters).
 MXU_TOL = (5e-4, 5e-4)
+# a bf16 output against a float32 oracle: half a bf16 ulp is 2**-9
+BF16_TOL = (1e-2, 1e-2)
 POOL_TOL = (0.0, 0.0)
 LRN_TOL = (1e-4, 1e-4)
 PLAN_TOL = (1e-3, 1e-7)
@@ -196,6 +199,27 @@ def _kernel_cases(key):
                   lambda q, ka, va, bt, p: ref.paged_attention_ref(
                       q, ka, va, bt, p),
                   MXU_TOL))
+    # the benchmark's serving shapes, bf16 arenas as served: minicpm_2b
+    # (36 KV heads of 64, 640 pages + trash, 16 slots) and qwen2_1_5b
+    # (2 KV heads of 128, 4096 pages + trash, 32 slots); the oracle runs
+    # in float32 on the same bf16 values
+    for b, hq, hk, d, tb in ((16, 36, 36, 64, 641), (32, hq, hk, d, 4097)):
+        table = jax.random.permutation(next(keys), tb - 1)[:b * nb] + 1
+        table = jnp.zeros((b * nb,), jnp.int32).at[:table.shape[0]].set(
+            table)
+        args = (normal((b, hq, 1, d)).astype(jnp.bfloat16),
+                normal((tb, hk, bs, d)).astype(jnp.bfloat16),
+                normal((tb, hk, bs, d)).astype(jnp.bfloat16),
+                table.reshape(b, nb).astype(jnp.int32),
+                jax.random.randint(next(keys), (b,), 0, nb * bs, jnp.int32))
+        f32 = lambda x: x.astype(jnp.float32)
+        cases.append((f"paged_attention bf16 B={b} H={hq}/{hk}x{d} "
+                      f"pages={tb}", args,
+                      lambda q, ka, va, bt, p: ops.paged_attention(
+                          q, ka, va, bt, p, interpret=False),
+                      lambda q, ka, va, bt, p: ref.paged_attention_ref(
+                          f32(q), f32(ka), f32(va), bt, p),
+                      BF16_TOL))
     return cases
 
 

@@ -121,12 +121,16 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(q: jax.Array, k_arena: jax.Array, v_arena: jax.Array,
                     block_tables: jax.Array, pos: jax.Array, *,
-                    interpret: Optional[bool] = None) -> jax.Array:
+                    interpret: Optional[bool] = None,
+                    steps=None) -> jax.Array:
     """Paged decode attention: q (B, HQ, 1, D) against block arenas
-    (TB, HK, BS, D) gathered through (B, NB) block tables."""
+    (TB, HK, BS, D) read through (B, NB) block tables, live pages only
+    (``pos < 0``: an empty slot).  ``steps``: the kernel's step list
+    (``paged_attention.paged_attention_steps``) when the caller shares one
+    across layers."""
     interpret = default_interpret() if interpret is None else interpret
     return paged_attention_pallas(q, k_arena, v_arena, block_tables, pos,
-                                  interpret=interpret)
+                                  interpret=interpret, steps=steps)
 
 
 # convenience: FC layer matching the paper's Eq. 1 (vector-matrix + f)

@@ -245,8 +245,34 @@ def decode_attention(q, k_cache, v_cache, *, pos: jax.Array,
     return out.reshape(b, hq, 1, d).astype(q.dtype)
 
 
+PAGED_IMPLS = ("auto", "ref", "pallas")
+
+
+def resolve_paged_impl(impl: str, backend: Optional[str] = None) -> str:
+    """The paged decode implementation ``impl`` names: ``"auto"`` is the
+    Pallas kernel when compiling for a TPU and the reference on any other
+    backend (``backend`` defaults to jax's default backend)."""
+    if impl not in PAGED_IMPLS:
+        raise ValueError(f"unknown paged attention impl {impl!r}")
+    if impl != "auto":
+        return impl
+    backend = jax.default_backend() if backend is None else backend
+    return "pallas" if backend == "tpu" else "ref"
+
+
+def paged_kernel_steps(k_arena, block_tables, pos, active):
+    """The Pallas kernel's step list for one engine step, shared by every
+    layer's :func:`paged_decode_attention` (``steps=``); ``k_arena`` is any
+    layer's arena, stacked or not."""
+    from ..kernels.paged_attention import paged_attention_steps
+    return paged_attention_steps(k_arena, block_tables,
+                                 jnp.where(active, pos, -1))
+
+
 def paged_decode_attention(q, k_arena, v_arena, block_tables, pos, *,
-                           max_seq: int, impl: str = "ref") -> jax.Array:
+                           max_seq: int, impl: str = "auto",
+                           active: Optional[jax.Array] = None,
+                           steps=None) -> jax.Array:
     """Single-token attention against a block-paged cache.
 
     q: (B, HQ, 1, D); arenas: (total_blocks + 1, HK, BS, D) — fixed-size
@@ -259,14 +285,21 @@ def paged_decode_attention(q, k_arena, v_arena, block_tables, pos, *,
     cache by construction (the gathered row equals the dense row at every
     attended position, and masked positions contribute exact zeros either
     way), which is the serving engine's correctness contract on CPU.
-    ``impl="pallas"`` runs kernels/paged_attention.py, which gathers pages
-    inside the kernel (online softmax — allclose, not bit-identical).
+    ``impl="pallas"`` runs kernels/paged_attention.py, which reads only
+    each slot's live pages (online softmax — allclose, not bit-identical);
+    a slot that ``active`` (B,) marks idle reads no page there and gets
+    zeros, since its output is discarded; ``steps`` is the kernel's step
+    list from :func:`paged_kernel_steps` of the same arguments.
+    ``impl="auto"`` is the kernel on a TPU and the reference elsewhere
+    (:func:`resolve_paged_impl`).
     """
+    impl = resolve_paged_impl(impl)
     if impl == "pallas":
-        out = kops.paged_attention(q, k_arena, v_arena, block_tables, pos)
+        if active is not None:
+            pos = jnp.where(active, pos, -1)
+        out = kops.paged_attention(q, k_arena, v_arena, block_tables, pos,
+                                   steps=steps)
         return out.astype(q.dtype)
-    if impl != "ref":
-        raise ValueError(f"unknown paged attention impl {impl!r}")
     from ..kernels.ref import paged_gather
     k = paged_gather(k_arena, block_tables, max_seq)
     v = paged_gather(v_arena, block_tables, max_seq)

@@ -82,9 +82,10 @@ class ModelConfig:
     attention_impl: str = "chunked"         # dot | chunked | pallas
     attn_chunk: int = 1024
     # paged serving decode: "ref" (gather + dense decode attention; bit-
-    # identical to the dense slot cache — the CPU/CI default) or "pallas"
-    # (kernels/paged_attention.py, in-kernel page gather on TPU)
-    paged_attention_impl: str = "ref"
+    # identical to the dense slot cache), "pallas" (kernels/
+    # paged_attention.py, reads only the live pages) or "auto" (the kernel
+    # when compiling for a TPU, the reference elsewhere)
+    paged_attention_impl: str = "auto"
     remat: bool = True
     frontend: str = "none"                  # none | audio | vision
     img_seq: int = 6404                     # vision stub: 4 tiles x 1601
@@ -771,7 +772,8 @@ def init_slot_cache_paged(cfg: ModelConfig, n_slots: int, max_seq: int, *,
 
 
 def _decode_attn_block_paged(p, x, cache, cfg: ModelConfig, pos,
-                             cross_feats, block_tables, active, max_seq):
+                             cross_feats, block_tables, active, max_seq,
+                             kernel_steps=None):
     """Paged counterpart of `_decode_attn_block`: identical q/k/v math
     (same rope over the same per-slot positions), but the new token's K/V
     is scattered into its slot's tail page and attention gathers through
@@ -806,7 +808,8 @@ def _decode_attn_block_paged(p, x, cache, cfg: ModelConfig, pos,
         with jax.named_scope("core"):
             out = attn_lib.paged_decode_attention(
                 q, k_arena, v_arena, block_tables, pos_a, max_seq=max_seq,
-                impl=cfg.paged_attention_impl)
+                impl=cfg.paged_attention_impl, active=active,
+                steps=kernel_steps)
         x = x + _merge_heads(out, p["attn"], cfg)
     if "xattn" in p and cross_feats is not None:
         x = x + _cross_attention(p, x, cross_feats, cfg)
@@ -833,11 +836,20 @@ def decode_step_slots_paged(params, cfg: ModelConfig, cache: Dict,
     pos = cache["pos"]
     cross_feats = cache.get("cross")
     block_tables = cache["block_tables"]
+    kernel_steps = None
+    if attn_lib.resolve_paged_impl(cfg.paged_attention_impl) == "pallas":
+        # the kernel's step list depends only on positions and tables:
+        # build it once here, not once per layer inside the layer scan
+        arena = next(c["k"] for c in sum(cache["layers"], ())
+                     if isinstance(c, dict) and "k" in c)
+        kernel_steps = attn_lib.paged_kernel_steps(arena, block_tables, pos,
+                                                   active)
 
     def block_step(btype, p, h, c):
         if btype == "attn":
             return _decode_attn_block_paged(p, h, c, cfg, pos, cross_feats,
-                                            block_tables, active, max_seq)
+                                            block_tables, active, max_seq,
+                                            kernel_steps)
         return _decode_block(btype, p, h, c, cfg, pos, cross_feats)
 
     return _decode_step_impl(params, cfg, cache, tokens, active,

@@ -32,6 +32,7 @@ open-loop arrival process (request.synthetic_workload) is offered against.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import Callable, Dict, List, Optional
@@ -40,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..models import attention as attn_lib
 from ..models import transformer as T
 from ..obs import Observability, default_clock
 from .batcher import ContinuousBatcher
@@ -129,6 +131,8 @@ def count_burst(obs, eng: "SlotEngine", burst: int,
     reg.counter("engine_prefill_tokens").inc(prefill_tokens)
     reg.counter("engine_prefill_steps").inc(prefill_steps)
     reg.counter("engine_decode_tokens").inc(decode_tokens)
+    reg.counter("engine_kv_pages_read").inc(eng.pages_read(burst, mask))
+    reg.gauge("engine_paged_attention_kernel").set(float(eng.paged_kernel))
     return {"steps": burst, "n_active": int(mask.sum()),
             "prefill_tokens": prefill_tokens,
             "prefill_steps": prefill_steps, "decode_tokens": decode_tokens}
@@ -211,12 +215,22 @@ class SlotEngine:
         self.kv_layout = kv_layout
         self.name = name                 # labels this engine's trace track
         n_slots = pool.n_slots
+        # the burst programs' paged attention, resolved for the device they
+        # run on (``paged_kernel``: the Pallas kernel, not the reference)
+        self._step_cfg = cfg
+        self.paged_kernel = False
         if kv_layout == "paged":
             self.cache = T.init_slot_cache_paged(
                 cfg, n_slots, pool.max_seq, block_size=pool.block_size,
                 total_blocks=pool.total_blocks)
             self._step_fn = functools.partial(T.decode_step_slots_paged,
                                               max_seq=pool.max_seq)
+            impl = attn_lib.resolve_paged_impl(
+                cfg.paged_attention_impl,
+                None if device is None else device.platform)
+            self._step_cfg = dataclasses.replace(cfg,
+                                                 paged_attention_impl=impl)
+            self.paged_kernel = impl == "pallas"
         else:
             self.cache = T.init_slot_cache(cfg, n_slots, pool.max_seq)
             self._step_fn = T.decode_step_slots
@@ -247,7 +261,7 @@ class SlotEngine:
         per token, so burst cost is dominated by device compute."""
         fn = self._burst_fns.get(k)
         if fn is None:
-            cfg = self.cfg
+            cfg = self._step_cfg
             step_fn = self._step_fn
 
             def burst(p, c, pr, pl, lt, ob, a):
@@ -359,6 +373,20 @@ class SlotEngine:
         prefill = int(fed.sum())
         return (prefill, int(fed.max(initial=0)),
                 burst * int(mask.sum()) - prefill)
+
+    def pages_read(self, burst: int, mask: np.ndarray) -> int:
+        """KV pages a burst of ``burst`` steps over the ``mask`` slots
+        attends, summed over its steps (0 for the dense layout): at step
+        ``i`` a slot whose lease holds ``p`` tokens at the burst's start
+        attends ``ceil((p + i + 1) / BS)`` pages, the pages the paged
+        kernel reads."""
+        if self.kv_layout != "paged":
+            return 0
+        pos = np.array([self.pool.lease(r.rid).written_tokens
+                        for s, r in enumerate(self.slots)
+                        if r is not None and mask[s]], np.int64)
+        at = pos[:, None] + np.arange(burst)
+        return int((at // self.pool.block_size + 1).sum())
 
     def dispatch(self, burst: int, active_np: np.ndarray) -> None:
         """Dispatch `burst` fused steps over the active slots (bucketed

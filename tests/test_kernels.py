@@ -198,6 +198,99 @@ def test_paged_attention_block_boundaries(rng, pos_list):
     _assert_close(got, want, jnp.bfloat16)
 
 
+# the benchmark cells' head geometries: minicpm_2b (MHA, 36 KV heads of 64)
+# and qwen2_1_5b (GQA, 12 query / 2 KV heads of 128); block 16, 2048 tokens
+SERVING_GEOMETRY = {"mha_36x64": (36, 36, 64), "gqa_12_2x128": (12, 2, 128)}
+
+
+def _poisoned_serving_case(rng, hq, hk, d, dtype):
+    """Slots at positions {0, 15, 16, 17, the derived chunk's last and
+    first tokens, 2047} and one empty slot (pos -1).  Live pages are
+    shuffled; a table entry past a slot's position names either a page of
+    its own (a reserved, unwritten page) or the padding page 0; those
+    pages and the trash page hold NaN.  Returns the poisoned arenas and
+    the same arenas with finite values there (what the reference, which
+    reads every entry of the table, is given)."""
+    from repro.kernels.paged_attention import pages_per_chunk
+    bs, nb = 16, 128
+    span = pages_per_chunk(hk * bs * d * jnp.dtype(dtype).itemsize,
+                           nb) * bs
+    pos = np.array([0, 15, 16, 17, span - 1, span, nb * bs - 1, -1],
+                   np.int32)
+    live = np.clip(pos // bs + 1, 0, nb)
+    reserved = np.arange(len(pos)) % 2 == 0         # the others pad with 0
+    n_pages = 1 + int(live.sum()) + int(
+        (nb - live)[reserved].sum()) + 1            # padding ... trash
+    ids = iter(rng.permutation(n_pages - 2) + 1)
+    bt = np.zeros((len(pos), nb), np.int32)
+    dead = [0, n_pages - 1]
+    for s in range(len(pos)):
+        for j in range(nb):
+            if j < live[s]:
+                bt[s, j] = next(ids)
+            elif reserved[s]:
+                bt[s, j] = next(ids)
+                dead.append(bt[s, j])
+    shape = (n_pages, hk, bs, d)
+    ka, va = (rng.normal(size=shape).astype(np.float32) for _ in range(2))
+    kn, vn = ka.copy(), va.copy()
+    kn[dead] = np.nan
+    vn[dead] = np.nan
+    q = jnp.asarray(rng.normal(size=(len(pos), hq, 1, d)), dtype)
+    arr = lambda a: jnp.asarray(a, dtype)
+    return (q, arr(kn), arr(vn), arr(ka), arr(va), jnp.asarray(bt),
+            jnp.asarray(pos))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("geometry", list(SERVING_GEOMETRY))
+def test_paged_attention_serving_geometry(rng, geometry, dtype):
+    """The kernel reads only live pages: NaN past every slot's position, in
+    the padding page and in the trash page never reaches the output; an
+    empty slot gets zeros; the rest matches the oracle."""
+    hq, hk, d = SERVING_GEOMETRY[geometry]
+    q, kn, vn, ka, va, bt, pos = _poisoned_serving_case(rng, hq, hk, d,
+                                                        dtype)
+    got = np.asarray(ops.paged_attention(q, kn, vn, bt, pos), np.float32)
+    want = np.asarray(ref.paged_attention_ref(q, ka, va, bt,
+                                              jnp.maximum(pos, 0)),
+                      np.float32)
+    assert np.isfinite(got).all()
+    empty = np.asarray(pos) < 0
+    np.testing.assert_array_equal(got[empty], 0.0)
+    _assert_close(got[~empty], want[~empty], dtype)
+
+
+@pytest.mark.parametrize("page_bytes,n_pages,want", [
+    (36 * 16 * 64 * 2, 128, 4),       # minicpm_2b page, bf16: 73,728 B
+    (2 * 16 * 128 * 2, 128, 32),      # qwen2_1_5b page, bf16: 8,192 B
+    (2 * 16 * 128 * 2, 4, 4),         # no more than the table holds
+    (4 * 2 ** 20, 128, 1),            # a page over the chunk: one a step
+])
+def test_pages_per_chunk_follows_page_bytes(page_bytes, n_pages, want):
+    from repro.kernels.paged_attention import pages_per_chunk
+    assert pages_per_chunk(page_bytes, n_pages) == want
+
+
+def test_paged_step_list_walks_live_chunks_only():
+    """Steps: one per live chunk (an empty slot gets one), slot-major; a
+    page past the position repeats its operand's page of the step before,
+    so the pipeline copies nothing for it."""
+    from repro.kernels.paged_attention import _step_list
+    bt = jnp.asarray(np.arange(1, 13, dtype=np.int32).reshape(3, 4))
+    pos = jnp.asarray(np.array([20, -1, 40], np.int32))   # 2, 0, 3 pages
+    n, slot_of, chunk_of, pages = _step_list(pos, bt, bs=16, ppc=2)
+    assert int(n) == 1 + 1 + 2
+    assert slot_of.shape == (3 * 2,)
+    np.testing.assert_array_equal(np.asarray(slot_of)[:4], [0, 1, 2, 2])
+    np.testing.assert_array_equal(np.asarray(chunk_of)[:4], [0, 0, 0, 1])
+    pages = np.asarray(pages).reshape(-1, 2)[:4]
+    # slot 0: pages 1, 2; slot 1 (empty) holds them; slot 2: pages 9, 10,
+    # then 11 and (dead) 10 again
+    np.testing.assert_array_equal(pages, [[1, 2], [1, 2], [9, 10],
+                                          [11, 10]])
+
+
 def test_paged_ref_trims_sequence_overhang(rng):
     # max_seq not a multiple of block_size: the gathered rows must trim the
     # tail pages' overhang, matching a dense cache of exactly max_seq

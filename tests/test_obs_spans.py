@@ -17,6 +17,7 @@ from repro.serving import (DisaggregatedEngineLoop, EngineLoop,
                            prefix_shared_workload, synthetic_workload)
 from repro.serving.engine_loop import SlotEngine
 from repro.serving.kv_pool import KVPool
+from repro.serving.request import Request
 from repro.serving.speculative import SpecPlan
 
 TINY = T.ModelConfig(
@@ -159,6 +160,48 @@ def test_bursts_count_every_prompt_and_output_token(tiny_params, kind):
     counters = obs.registry.counters
     for k, v in total.items():
         assert counters[f"engine_{k}"].value == v
+
+
+@pytest.mark.parametrize("kind", ["plain", "prefix_shared", "disagg"])
+def test_pages_read_sum_each_steps_live_pages(tiny_params, kind):
+    """``engine_kv_pages_read`` equals a hand count: a request steps at
+    positions ``shared .. prompt_len + max_new - 2`` (on whichever engine),
+    and at position p attends ``p // 16 + 1`` pages; the registry also
+    says which paged attention the burst programs were built with (the
+    reference, on the CPU)."""
+    if kind == "prefix_shared":
+        reqs = prefix_shared_workload(8, rate=1e9, vocab=TINY.vocab,
+                                      shared_prefix_len=20, seed=3)
+    else:
+        reqs = synthetic_workload(9, rate=50.0, vocab=TINY.vocab,
+                                  prompt_lens=(4, 8, 13, 30),
+                                  gen_lens=(1, 3, 6, 12), seed=11)
+    obs = _run(tiny_params, reqs, disagg=kind == "disagg",
+               prefix_sharing=kind == "prefix_shared")
+    want = sum(p // 16 + 1 for r in reqs
+               for p in range(r.shared_tokens,
+                              r.prompt_len + r.max_new_tokens - 1))
+    snap = obs.registry.snapshot()
+    assert snap["counters"]["engine_kv_pages_read"] == want
+    assert snap["gauges"]["engine_paged_attention_kernel"] == 0.0
+
+
+def test_pages_read_of_a_burst():
+    eng = SlotEngine.__new__(SlotEngine)     # schedule state only
+    eng.kv_layout = "paged"
+    eng.pool = KVPool(n_slots=3, max_seq=64, block_size=16, total_blocks=12)
+    eng.slots = [None] * 3
+    for s, (rid, written) in enumerate([(1, 14), (2, 0), (3, 40)]):
+        eng.pool.alloc(rid, 64)
+        eng.pool.note_write(rid, written)
+        eng.slots[s] = Request(rid=rid, prompt=np.zeros(4, np.int32),
+                               max_new_tokens=1, arrival=0.0)
+    mask = np.array([True, False, True])
+    # slot 0 at 14, 15, 16: 1 + 1 + 2 pages; slot 2 at 40, 41, 42: 3 each
+    assert eng.pages_read(3, mask) == 4 + 9
+    assert eng.pages_read(3, np.zeros(3, bool)) == 0
+    eng.kv_layout = "dense"
+    assert eng.pages_read(3, mask) == 0
 
 
 def test_token_split_of_a_burst():

@@ -158,6 +158,70 @@ def test_pool_shared_churn_conserves_refcounts():
 
 
 # ------------------------------------------- bitwise decode equivalence
+# ------------------------------------------- which paged attention serves
+@pytest.mark.parametrize("impl,backend,want", [
+    ("auto", "cpu", "ref"), ("auto", "tpu", "pallas"), ("auto", "gpu", "ref"),
+    ("ref", "tpu", "ref"), ("pallas", "cpu", "pallas")])
+def test_paged_impl_resolves_by_backend(impl, backend, want):
+    from repro.models.attention import resolve_paged_impl
+    assert resolve_paged_impl(impl, backend) == want
+
+
+def test_paged_impl_rejects_unknown_names():
+    from repro.models.attention import resolve_paged_impl
+    with pytest.raises(ValueError, match="unknown paged attention impl"):
+        resolve_paged_impl("gather", "cpu")
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_engine_builds_its_bursts_with_the_backends_paged_attention(
+        tiny_params, monkeypatch, backend):
+    """The default configuration serves the reference on the CPU (what the
+    bitwise paged == dense tests below test) and the kernel on a TPU; the
+    engine records which (nothing is compiled here)."""
+    assert TINY.paged_attention_impl == "auto"
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    eng = SlotEngine(TINY, tiny_params, KVPool(n_slots=2, max_seq=MAX_LEN,
+                                               block_size=BS),
+                     kv_layout="paged")
+    assert eng.paged_kernel is (backend == "tpu")
+    assert eng._step_cfg.paged_attention_impl == (
+        "pallas" if backend == "tpu" else "ref")
+    dense = SlotEngine(TINY, tiny_params, KVPool(n_slots=2, max_seq=MAX_LEN,
+                                                 block_size=BS),
+                       kv_layout="dense")
+    assert dense.paged_kernel is False
+
+
+def test_paged_decode_attention_routes_by_backend(rng, monkeypatch):
+    """On a TPU backend the kernel runs, and an idle slot asks it for no
+    page (position -1); on the CPU the reference runs, bitwise."""
+    from repro.kernels import ref
+    from repro.models import attention as attn_lib
+    b, hq, hk, d, bs, nb = 3, 4, 2, 8, 4, 3
+    q = jnp.asarray(rng.normal(size=(b, hq, 1, d)), jnp.float32)
+    ka = jnp.asarray(rng.normal(size=(b * nb + 1, hk, bs, d)), jnp.float32)
+    va = jnp.asarray(rng.normal(size=(b * nb + 1, hk, bs, d)), jnp.float32)
+    bt = jnp.asarray(np.arange(1, b * nb + 1, dtype=np.int32).reshape(b, nb))
+    pos = jnp.asarray(np.array([3, 9, 5], np.int32))
+    active = jnp.asarray(np.array([True, False, True]))
+    got = attn_lib.paged_decode_attention(q, ka, va, bt, pos, max_seq=10,
+                                          active=active)
+    want = ref.paged_attention_ref(q, ka, va, bt, pos, max_seq=10)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    seen = {}
+
+    def kernel(q, ka, va, bt, pos, steps=None):
+        seen["pos"] = np.asarray(pos)
+        return jnp.zeros_like(q)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(attn_lib.kops, "paged_attention", kernel)
+    attn_lib.paged_decode_attention(q, ka, va, bt, pos, max_seq=10,
+                                    active=active)
+    np.testing.assert_array_equal(seen["pos"], [3, -1, 5])
+
+
 def test_paged_decode_step_bitwise_matches_dense(tiny_params):
     """decode_step_slots_paged == decode_step_slots bit-for-bit across
     steps that cross odd seq % block_size boundaries, with inactive slots

@@ -3,8 +3,9 @@
 No chip is attached: the TPU compiler that ships with libtpu compiles for a
 described ``v5e:2x2`` topology, and refuses what the chip would refuse
 (unsupported vector layouts, more VMEM than a kernel may use).  Shapes are
-the real ones: AlexNet's layers at ``alexnet_full_spec`` geometry and
-qwen2_1_5b's attention widths.  Nothing runs, so these check compilation,
+the real ones: AlexNet's layers at ``alexnet_full_spec`` geometry,
+qwen2_1_5b's attention widths, and paged decode at both benchmark cells'
+serving shapes.  Nothing runs, so these check compilation,
 not results (tests/test_kernels.py checks results in interpret mode).
 
 This is the only file that describes the chip.  The topology is built in a
@@ -23,6 +24,10 @@ from repro.kernels import ops
 
 IMAGES = 8
 HQ, HK, HEAD_DIM = 12, 2, 128            # qwen2_1_5b attention widths
+# the benchmark cells' paged decode at their serving shapes, bf16 arenas:
+# (slots, query heads, KV heads, head_dim, arena pages incl. the trash page)
+PAGED_SERVING = {"paged_attention_minicpm_2b": (16, 36, 36, 64, 641),
+                 "paged_attention_qwen2_1_5b": (32, HQ, HK, HEAD_DIM, 4097)}
 LAYERS = {s.name: s for s in alexnet_full_spec()}
 
 
@@ -83,23 +88,44 @@ def _attention_case(name):
                                                      interpret=False)),
                 _f32((1, HQ, s, HEAD_DIM), (1, HK, s, HEAD_DIM),
                      (1, HK, s, HEAD_DIM)))
-    b, bs, nb = 8, 16, 128                    # 8 slots x 2048 tokens
+    bs, nb = 16, 128                          # 2048 tokens a slot
+    fn = (lambda q, ka, va, bt, p: ops.paged_attention(
+        q, ka, va, bt, p, interpret=False))
+    if name in PAGED_SERVING:
+        b, hq, hk, d, tb = PAGED_SERVING[name]
+        return fn, ([((b, hq, 1, d), jnp.bfloat16)]
+                    + [((tb, hk, bs, d), jnp.bfloat16)] * 2
+                    + [((b, nb), jnp.int32), ((b,), jnp.int32)])
+    b = 8
     tb = b * nb + 1
-    return ((lambda q, ka, va, bt, p: ops.paged_attention(
-        q, ka, va, bt, p, interpret=False)),
-        _f32((b, HQ, 1, HEAD_DIM), (tb, HK, bs, HEAD_DIM),
-             (tb, HK, bs, HEAD_DIM)) + [((b, nb), jnp.int32),
-                                        ((b,), jnp.int32)])
+    return fn, (_f32((b, HQ, 1, HEAD_DIM), (tb, HK, bs, HEAD_DIM),
+                     (tb, HK, bs, HEAD_DIM)) + [((b, nb), jnp.int32),
+                                                ((b,), jnp.int32)])
 
 
 @pytest.mark.parametrize("name", list(LAYERS) + ["flash_attention",
-                                                 "paged_attention"])
+                                                 "paged_attention"]
+                         + list(PAGED_SERVING))
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, shapes = (_layer_case(name) if name in LAYERS
                   else _attention_case(name))
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
             for shape, dtype in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("name", list(PAGED_SERVING))
+def test_paged_serving_compiles_under_highest_matmul_precision(one_chip,
+                                                               name):
+    """chip_smoke.py checks kernels under ``default_matmul_precision(
+    "highest")``; Mosaic takes no such precision for bf16 operands, so the
+    kernel pins its own."""
+    fn, shapes = _attention_case(name)
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 

@@ -291,13 +291,12 @@ def build(cell: spec_lib.Cell, *, seed: int, peaks: Dict, annotate: bool):
     compiled (or loaded) and each slot warmed by one short request.
     Returns ``(cfg, loop, mesh)``."""
     from . import program
-    from .weights import dims_from_config
-    cfg = program.model_config(cell.config)
+    cfg = cell.model.program_config(cell.config)
     sv = cell.config["serving"]
-    params, mesh = program.make_params(cfg, dims_from_config(cell.config),
-                                       seed)
+    params, mesh = program.make_params(cell.model, cell.config, cfg, seed)
     loop = program.BenchLoop(
-        cfg, params, peaks=peaks, annotate=annotate, n_slots=sv["n_slots"],
+        cfg, params, model=cell.model, config=cell.config, peaks=peaks,
+        annotate=annotate, n_slots=sv["n_slots"],
         max_seq=sv["max_seq"], block_size=sv["block_size"],
         total_blocks=sv["total_blocks"], kv_layout=sv["kv_layout"])
     del params
@@ -364,13 +363,13 @@ def check(cell: spec_lib.Cell, run: Run, prompts, *, seed: int,
     program's device memory is freed.  Returns ``(correct, checks)``;
     with ``control`` the checks also hold the float8 control's gap."""
     from . import reference
-    from .weights import dims_from_config, root_key
+    from .weights import root_key
     free_device_memory()
     corr = cell.config["correct"]
     samples = sample_requests(run.requests, prompts,
                               corr["sample_requests"], seed)
-    ref = reference.Reference(dims_from_config(cell.config), root_key(seed),
-                              cell.config["serving"]["max_seq"])
+    ref = cell.model.Reference(cell.config, root_key(seed),
+                               cell.config["serving"]["max_seq"])
     gap, ctrl = None, None               # nothing finished: not correct
     if samples:
         gap = reference.served_gaps(ref, samples, control=control)

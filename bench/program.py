@@ -1,6 +1,7 @@
-"""Everything the benchmark takes from the program under test: its model
-configuration, its serving loop (built as ``serving.api.serve`` builds it)
-and the ``Request`` objects it serves.
+"""Everything the benchmark takes from the program under test: its
+parameter tree (made from a model module's weights), its serving loop
+(built as ``serving.api.serve`` builds it) and the ``Request`` objects it
+serves.
 
 ``BenchLoop`` is ``EngineLoop`` with the hooks ``OpenLoopDriver`` calls
 wrapped, without touching the program: each hook is a host span
@@ -16,10 +17,8 @@ import time
 from typing import Callable, Dict, List, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import registry
 from repro.launch.mesh import make_host_mesh
 from repro.models import sharding as shard_lib
 from repro.models import transformer as T
@@ -34,51 +33,17 @@ class StopServing(Exception):
     """Raised from a loop hook to end a run once its requests are in."""
 
 
-def model_config(config: Dict) -> T.ModelConfig:
-    """The program's configuration for a configuration file: the registry
-    entry it names, cut in depth and given the norm epsilon as the file
-    says (options the program has), then checked key by key against the
-    published numbers the file holds.  The program must store the weights
-    at least as exactly as the checkpoint's ``torch_dtype`` holds them."""
-    cfg = registry.get(config["arch"]).config
-    cfg = dataclasses.replace(cfg, n_layers=config["num_hidden_layers"],
-                              norm_eps=float(config["rms_norm_eps"]),
-                              # as serving.api.serve runs it
-                              scan_chunk=min(cfg.scan_chunk, 16))
-    want = {"d_model": config["hidden_size"],
-            "n_heads": config["num_attention_heads"],
-            "n_kv_heads": config["num_key_value_heads"],
-            "hd": config["head_dim"], "d_ff": config["intermediate_size"],
-            "vocab": config["vocab_size"],
-            "qkv_bias": config["attention_bias"],
-            "tie_embeddings": config["tie_word_embeddings"],
-            "rope_theta": float(config["rope_theta"]),
-            "norm_eps": float(config["rms_norm_eps"]),
-            "block_pattern": ("attn",), "n_experts": 0, "gated_mlp": True,
-            "attn_window": None,
-            "compute_dtype": jnp.dtype(config["compute_dtype"])}
-    got = {k: getattr(cfg, k) for k in want}
-    got["compute_dtype"] = jnp.dtype(got["compute_dtype"])
-    bad = {k: (got[k], v) for k, v in want.items() if got[k] != v}
-    stored = jnp.dtype(cfg.param_dtype)
-    if stored not in (jnp.dtype(config["torch_dtype"]), jnp.dtype("float32")):
-        bad["param_dtype"] = (stored, config["torch_dtype"])
-    if bad:
-        raise ValueError(f"{config['arch']}: the program's configuration "
-                         f"differs from the file's (program, file): {bad}")
-    return cfg
-
-
-def make_params(cfg: T.ModelConfig, dims: weights_lib.Dims, seed: int):
-    """The seed's weights (``dims`` from the configuration file), made on
-    the device in one jitted call, in the program's tree and storage
-    dtype, with the shardings ``api.build_params`` gives them."""
+def make_params(model, config: Dict, cfg: T.ModelConfig, seed: int):
+    """The seed's weights (``model.program_params`` from the configuration
+    file), made on the device in one jitted call, in the program's tree
+    and storage dtype, with the shardings ``api.build_params`` gives
+    them."""
     mesh = make_host_mesh()
     shapes = jax.eval_shape(functools.partial(T.init_params, cfg=cfg),
                             jax.random.PRNGKey(0))
     shardings = shard_lib.param_shardings(
         cfg, shard_lib.make_policy(cfg, mesh), shapes)
-    make = jax.jit(functools.partial(weights_lib.program_params, dims=dims,
+    make = jax.jit(functools.partial(model.program_params, config=config,
                                      param_dtype=cfg.param_dtype),
                    out_shardings=shardings)
     with mesh:
@@ -117,11 +82,13 @@ class Dispatch:
 
 
 class BenchLoop(EngineLoop):
-    """The colocated loop with its driver hooks wrapped."""
+    """The colocated loop with its driver hooks wrapped; ``model`` and
+    ``config`` count each burst's work (``model.step_counts``)."""
 
-    def __init__(self, cfg, params, *, peaks: Dict, annotate: bool, **kw):
+    def __init__(self, cfg, params, *, model, config: Dict, peaks: Dict,
+                 annotate: bool, **kw):
         super().__init__(cfg, params, **kw)
-        self.counts = step_counts(cfg, params, self)
+        self.counts = step_counts(model, config, cfg, params, self)
         self.peaks = peaks
         self.annotate = annotate
         self.dispatches: List[Dispatch] = []
@@ -214,12 +181,9 @@ class CompileClock:
         mon.unregister_event_listener(self._listeners[1])
 
 
-def step_counts(cfg: T.ModelConfig, params, loop: EngineLoop
-                ) -> counts_lib.StepCounts:
+def step_counts(model, config: Dict, cfg: T.ModelConfig, params,
+                loop: EngineLoop):
+    """The model's counts, at the loop's KV arena dtype."""
     kv_leaf = jax.tree.leaves(loop.engine.cache["layers"])[0]
-    return counts_lib.step_counts(
-        n_layers=cfg.n_layers, d_model=cfg.d_model, n_heads=cfg.n_heads,
-        n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd, d_ff=cfg.d_ff,
-        vocab=cfg.vocab, param_leaves=jax.tree.leaves(params),
-        compute_itemsize=jnp.dtype(cfg.compute_dtype).itemsize,
-        kv_itemsize=np.dtype(kv_leaf.dtype).itemsize)
+    return model.step_counts(config, cfg, params,
+                             np.dtype(kv_leaf.dtype).itemsize)

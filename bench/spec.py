@@ -1,5 +1,5 @@
-"""Find a cell's configuration, traffic mix, generator and metric readers
-by the names ``BENCHMARK.json`` gives them.
+"""Find a cell's configuration, its model module, traffic mix, generator
+and metric readers by the names ``BENCHMARK.json`` and the files give them.
 
 Nothing here knows a cell, a model or a metric by name: a new one is a new
 file and a new entry, and no existing file changes.
@@ -9,6 +9,7 @@ from __future__ import annotations
 import dataclasses
 import importlib.util
 import json
+import sys
 from pathlib import Path
 from types import ModuleType
 from typing import Callable, Dict, List
@@ -28,6 +29,7 @@ class Cell:
     name: str
     chips: int
     config: Dict
+    model: ModuleType              # bench/models/<config["model"]>.py
     traffic: Dict
     generator: ModuleType
     end_to_end: List[Metric]
@@ -36,13 +38,29 @@ class Cell:
 
 def load_module(path: Path, name: str) -> ModuleType:
     """Import one file by path (metric files carry dots in their names,
-    so they cannot be imported as packages)."""
+    so they cannot be imported as packages).  The module is entered in
+    ``sys.modules`` under ``name`` before it runs, as an import would
+    enter it (a dataclass looks its module up there)."""
     spec = importlib.util.spec_from_file_location(name, path)
     if spec is None or spec.loader is None:
         raise FileNotFoundError(path)
     mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    sys.modules[name] = mod
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[name]
+        raise
     return mod
+
+
+def load_model(name: str, *, root: Path = ROOT) -> ModuleType:
+    """The model module ``bench/models/<name>.py`` (the interface is in
+    ``bench/models/__init__.py``); there is no default."""
+    path = root / "bench" / "models" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"model {name!r} has no module at {path}")
+    return load_module(path, f"bench_model_{name}")
 
 
 def _metric(entry: Dict, bench_dir: Path) -> Metric:
@@ -70,6 +88,10 @@ def load_cell(name: str, *, root: Path = ROOT) -> Cell:
     configs = {c["name"]: c for c in spec["configs"]}
     cfg_entry = configs[w["config"]]
     config = json.loads((root / cfg_entry["file"]).read_text())
+    if "model" not in config:
+        raise KeyError(f"{cfg_entry['file']} names no model module "
+                       f"(\"model\": bench/models/<name>.py)")
+    model = load_model(config["model"], root=root)
     traffic = json.loads(
         (bench_dir / "traffic" / f"{w['traffic']}.json").read_text())
     generator = load_module(
@@ -80,7 +102,7 @@ def load_cell(name: str, *, root: Path = ROOT) -> Cell:
     per_layer = [_metric(m, bench_dir) for m in spec["per_layer"]
                  if _reports(m, name)]
     return Cell(name=name, chips=int(w["chips"]), config=config,
-                traffic=traffic, generator=generator, end_to_end=e2e,
+                model=model, traffic=traffic, generator=generator, end_to_end=e2e,
                 per_layer=per_layer)
 
 

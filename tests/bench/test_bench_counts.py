@@ -1,5 +1,6 @@
-"""FLOPs and bytes from shapes (bench/counts.py), against hand counts for
-both configurations, with the program's own parameter arrays."""
+"""FLOPs and bytes from shapes (the dense decoder's ``step_counts`` and
+bench/counts.py), against hand counts for both configurations, with the
+program's own parameter arrays."""
 import dataclasses
 import functools
 import json
@@ -13,9 +14,10 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
-from bench import counts, program  # noqa: E402
-from repro.configs import registry  # noqa: E402
+from bench import counts, spec  # noqa: E402
 from repro.models import transformer as T  # noqa: E402
+
+MODEL = spec.load_model("dense_decoder")
 
 # hand counts: (matmul weights a token multiplies, parameters stored,
 # attention FLOPs per attended key, KV bytes per token at bf16)
@@ -29,25 +31,14 @@ HAND = {
 }
 
 
-def _config(name):
-    path = ROOT / f"bench/configs/{name}.json"
-    if path.is_file():
-        return program.model_config(json.loads(path.read_text()))
-    # minicpm_2b has no cell yet: its registry entry, cut to 10 layers
-    return dataclasses.replace(registry.get(name).config, n_layers=10)
-
-
 def _counts(name, param_dtype=None):
-    cfg = _config(name)
+    config = json.loads((ROOT / f"bench/configs/{name}.json").read_text())
+    cfg = MODEL.program_config(config)
     if param_dtype is not None:
         cfg = dataclasses.replace(cfg, param_dtype=param_dtype)
-    leaves = jax.tree.leaves(jax.eval_shape(
-        functools.partial(T.init_params, cfg=cfg), jax.random.PRNGKey(0)))
-    return counts.step_counts(
-        n_layers=cfg.n_layers, d_model=cfg.d_model, n_heads=cfg.n_heads,
-        n_kv_heads=cfg.n_kv_heads, head_dim=cfg.hd, d_ff=cfg.d_ff,
-        vocab=cfg.vocab, param_leaves=leaves, compute_itemsize=2,
-        kv_itemsize=2)
+    shapes = jax.eval_shape(functools.partial(T.init_params, cfg=cfg),
+                            jax.random.PRNGKey(0))
+    return MODEL.step_counts(config, cfg, shapes, kv_itemsize=2)
 
 
 @pytest.mark.parametrize("name", sorted(HAND))
@@ -61,6 +52,7 @@ def test_counts_match_hand_counts(name):
     assert c.param_bytes_narrow == 2 * n_params
     assert c.token_flops(0) == 2 * mm + attn
     assert c.token_flops(99) == 2 * mm + 100 * attn
+    assert c.token_kv_bytes(99) == 100 * kv
 
 
 @pytest.mark.parametrize("name", sorted(HAND))

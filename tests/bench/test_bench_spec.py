@@ -61,6 +61,28 @@ def test_every_cell_resolves(cell):
     assert c.end_to_end and c.per_layer
     assert "setup_s" in [m.name for m in c.end_to_end]
     assert callable(c.generator.generate)
+    for f in ("program_config", "program_params", "Reference", "step_counts",
+              "small"):
+        assert callable(getattr(c.model, f)), f
+
+
+@pytest.mark.parametrize("model", [None, "no_such_model"])
+def test_a_configuration_needs_its_model_module(tmp_path, model):
+    """A configuration file that names no model module, or one that is not
+    there, is refused: no model is assumed."""
+    shutil.copytree(ROOT / "bench", tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", tmp_path)
+    cell = BENCH["workloads"][0]
+    path = tmp_path / next(c["file"] for c in BENCH["configs"]
+                           if c["name"] == cell["config"])
+    cfg = json.loads(path.read_text())
+    del cfg["model"]
+    if model is not None:
+        cfg["model"] = model
+    path.write_text(json.dumps(cfg))
+    with pytest.raises((KeyError, FileNotFoundError), match="model"):
+        spec.load_cell(cell["name"], root=tmp_path)
 
 
 def test_peaks_table_is_keyed_by_device_kind():
